@@ -28,8 +28,9 @@ Produces the evidence file committed as ``BENCH_PALLAS.json``:
     WavePlan.
 
 ``--smoke`` is the tier-1 CI gate: all nine Table-1 kernels (and the
-speculative three) at reduced scales through the real Pallas path
-(interpret mode), both trace modes, oracle-asserted, no JSON.
+speculative three) at reduced scales through the real wave-backend
+path on the default JAX device, both trace modes, oracle-asserted, no
+JSON.
 
 Usage:
     PYTHONPATH=src:. python benchmarks/bench_pallas.py \
@@ -45,12 +46,13 @@ import time
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import executor, loopir as ir, programs, simulator
 from repro.kernels import wave_exec
 from benchmarks.paper_table1 import SCALES, scaled
 
-# tier-1 smoke scales: small enough that 12 kernels x 2 trace modes of
-# interpret-mode Pallas fit the tier-1 wall-clock budget
+# tier-1 smoke scales: small enough that 12 kernels x 2 trace modes
+# fit the tier-1 wall-clock budget on XLA's CPU backend
 SMOKE_SCALES = {
     "RAWloop": 256, "WARloop": 256, "WAWloop": 256,
     "bnn": 16, "pagerank": 24, "fft": 64, "matpower": 16,
@@ -160,7 +162,7 @@ def run_kernel(name, scale, *, trace_mode="auto", check=True,
     t_plan = time.time() - t0
 
     t0 = time.time()
-    res = wave_exec.run_plan(plan, arrays, interpret=True, check=check)
+    res = wave_exec.run_plan(plan, arrays, check=check)
     t_wave = time.time() - t0
 
     for k in oracle:
@@ -195,7 +197,7 @@ def run_kernel(name, scale, *, trace_mode="auto", check=True,
     if seq_steps:
         limit = min(seq_steps, plan.stats.n_requests)
         seq = wave_exec.run_sequential(
-            plan, arrays, interpret=True, check=False, max_steps=limit,
+            plan, arrays, check=False, max_steps=limit,
         )
         # measured and extrapolated numbers never share a key: the
         # measured wall/steps are always reported as such, and only a
@@ -317,6 +319,7 @@ def main():
         help="tier-1 CI smoke: reduced scales, oracle-asserted, no JSON",
     )
     a = ap.parse_args()
+    use_compile_cache()
     if a.smoke:
         smoke()
         return

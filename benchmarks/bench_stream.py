@@ -16,14 +16,14 @@ Produces the evidence file committed as ``BENCH_STREAM.json``:
   * bit-exactness everywhere: every engine / backend / depth result is
     asserted array-equal against the hand-written numpy oracles
     (kernels/dynloop/ref.py) — never against each other only,
-  * the Pallas wave path (interpret mode) wall-clock at the default
+  * the wave-backend path (``backend="pallas"``) wall-clock at the default
     depth, with the run_sequential one-request-per-step baseline over a
     ``--seq-steps`` prefix (measured and extrapolated numbers never
     share a key, same convention as bench_pallas.py).
 
 ``--smoke`` is the tier-1 CI gate: all three kernels at reduced scales
 through BOTH engines (cycle + event, cycle counts asserted equal), the
-numpy executor and the real Pallas path at depths 1 and 4,
+numpy executor and the real wave-backend path at depths 1 and 4,
 oracle-asserted, no JSON.
 
 Usage:
@@ -39,12 +39,13 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import executor, loopir as ir, programs, simulator
 from repro.kernels import wave_exec
 from repro.kernels.dynloop import ref
 
-# tier-1 smoke scales: small enough for the cycle engine and
-# interpret-mode Pallas at two depths inside the tier-1 budget
+# tier-1 smoke scales: small enough for the cycle engine and the
+# wave backend at two depths inside the tier-1 budget
 SMOKE_SCALES = {"stream_dot": 12, "filter_pipe": 48, "stream_join": 32}
 DEPTHS = (1, 2, 4)
 DEFAULT_DEPTH = 4
@@ -139,7 +140,7 @@ def run_kernel(name, scale, *, engines=("event",), depths=DEPTHS,
                 )
         if depth in pallas_depths:
             t0 = time.time()
-            res_pl = wave_exec.run_plan(plan, arrays, interpret=True)
+            res_pl = wave_exec.run_plan(plan, arrays)
             t_wave = time.time() - t0
             assert res_pl.complete
             _assert_oracle(
@@ -150,8 +151,7 @@ def run_kernel(name, scale, *, engines=("event",), depths=DEPTHS,
             if seq_steps:
                 limit = min(seq_steps, plan.stats.n_requests)
                 seq = wave_exec.run_sequential(
-                    plan, arrays, interpret=True, check=False,
-                    max_steps=limit,
+                    plan, arrays, check=False, max_steps=limit,
                 )
                 d["seq_extrapolated"] = not seq.complete
                 d["seq_steps_measured"] = seq.n_steps
@@ -255,6 +255,7 @@ def main():
         "backends, oracle-asserted, no JSON",
     )
     a = ap.parse_args()
+    use_compile_cache()
     if a.smoke:
         smoke()
         return

@@ -42,9 +42,9 @@ The module is split along the backend seam (DESIGN.md §2):
   * ``execute`` drives a plan through a backend: ``backend="numpy"``
     (default) is the in-process reference replay below;
     ``backend="pallas"`` hands the same plan to
-    ``repro.kernels.wave_exec`` which executes every wave as a Pallas
-    gather→compute→scatter step. Both must produce arrays bit-identical
-    to the sequential oracle.
+    ``repro.kernels.wave_exec`` which executes every batched step as an
+    XLA gather→scatter on the JAX device. Both must produce arrays
+    bit-identical to the sequential oracle.
 
 ``frontier_merge`` is the vectorized monotonic-streams primitive shared
 with the Pallas kernels and the MoE dispatch path.
@@ -179,6 +179,9 @@ class ExecResult:
     stats: WaveStats
     waves: np.ndarray  # per-request wave index, in program order
     plan: Optional[WavePlan] = None
+    # backend="pallas": the device run's record (kernels/wave_exec
+    # WaveExecResult — resolve/device seconds, segment count)
+    run: Optional[object] = None
 
 
 def frontier_merge(src_addr: np.ndarray, dst_addr: np.ndarray) -> np.ndarray:
@@ -1051,9 +1054,10 @@ def execute(
     tables) and drives it through a backend:
 
       * ``backend="numpy"`` — the reference replay in this module,
-      * ``backend="pallas"`` — ``repro.kernels.wave_exec``: each wave
-        runs as a data-parallel Pallas gather→compute→scatter step over
-        a flat bit-exact memory image (interpret mode on CPU).
+      * ``backend="pallas"`` — ``repro.kernels.wave_exec``: each batched
+        step runs as a data-parallel XLA gather→scatter over a flat
+        bit-exact memory image on the default JAX device (the TPU on a
+        chip host, XLA's CPU backend under ``JAX_PLATFORMS=cpu``).
 
     Both compute store values through the op tables and are asserted
     request-exact against the oracle reference stream; final arrays are
@@ -1100,14 +1104,17 @@ def execute(
     )
     if validate_hints:
         validate_plan_hints(plan)
+    run = None
     if backend == "numpy":
         out = _replay_numpy(plan, arrays)
     elif backend == "pallas":
         from repro.kernels import wave_exec
 
-        out = wave_exec.run_plan(plan, arrays).arrays
+        run = wave_exec.run_plan(plan, arrays)
+        out = run.arrays
     else:
         raise ValueError(f"unknown backend {backend!r}")
     return ExecResult(
-        arrays=out, stats=plan.stats, waves=plan.req_wave, plan=plan
+        arrays=out, stats=plan.stats, waves=plan.req_wave, plan=plan,
+        run=run,
     )

@@ -1,4 +1,4 @@
-"""Pallas TPU kernels: batched wave steps as gather→scatter
+"""Device step of the wave backend: batched wave steps as gather→scatter
 (DESIGN.md §2 → the backend half of the WavePlan contract).
 
 A step is a batch of conflict-free waves (WavePlan contract 5): no two
@@ -10,126 +10,70 @@ with gather strictly before scatter:
     load_vals[i] = mem[addr[i]]                        (gather, pre-step)
     mem[addr[i]] = sval[i]   where is_store & valid    (scatter)
 
-Bit-exactness is by construction: the kernel only *moves* data. The
+Bit-exactness is by construction: the step only *moves* data. The
 f64 memory image travels as ``(M, 2)`` uint32 bit-pattern rows — TPUs
 have no f64 ALU, but a DU does not compute either; it disambiguates
 and moves. Store values arrive precomputed by the op tables
 (``core/optable``) from the gathers of *strictly earlier* steps
-(contract 5), which is what makes the single-kernel gather+scatter
+(contract 5), which is what makes the single gather+scatter step
 sound: nothing gathered in this step feeds a store of this step.
 
 The scatter touches **only write lanes**: every non-write lane (loads,
 §6-invalid stores, padding) is redirected to the scratch row ``M - 1``
 past the real image, so a load may share a real address with a store
 in the same step (the batch-internal WAR) without racing it through a
-duplicate-index scatter. Scratch-row content is never observed — pad
-lanes gather it and the caller discards those lanes.
+duplicate-index scatter. Non-write lanes all write back the scratch
+row's own pre-step content, so the scatter's result does not depend on
+the order in which duplicate scratch indices are applied. Scratch-row
+content is never observed — pad lanes gather it and the caller
+discards those lanes.
 
-``wave_loop`` drives a whole *segment* of equal-width steps through one
-``jax.lax.fori_loop`` over the stacked per-step tables, so the host
-dispatches one call per segment instead of one per step — step count
-stops dominating wall-clock (ROADMAP item 1).
+The step is plain XLA gather/scatter: the image stays in device memory
+(HBM on a TPU), so its size is bounded by device memory rather than by
+a kernel's fast-memory block, and one program serves every platform —
+there is no interpreter mode. ``wave_loop`` drives a whole *segment* of
+equal-width steps through one ``jax.lax.fori_loop`` over the stacked
+per-step tables, so the host dispatches one call per segment instead
+of one per step.
 """
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 
-def _wave_kernel(mem_ref, addr_ref, write_ref, sval_ref, out_mem_ref,
-                 vals_ref):
-    mem = mem_ref[...]  # (M, 2) uint32 f64 bit patterns
-    addr = addr_ref[...]  # (W,) int32 in [0, M); see wave_step contract
-    rows = jnp.take(mem, addr, axis=0, mode="clip")  # gather (pre-step)
-    vals_ref[...] = rows
-    write = write_ref[...] == 1  # (W,) store & valid & !pad
-    # scatter only write lanes; everything else lands on the scratch
-    # row M-1, whose content is never observed (module doc)
-    scat = jnp.where(write, addr, mem.shape[0] - 1)
-    out_mem_ref[...] = mem.at[scat].set(
-        jnp.where(write[:, None], sval_ref[...], mem[-1])
-    )
-
-
-def _step_call(mem, addr, write, sval, interpret):
-    m = mem.shape[0]
-    w = addr.shape[0]
-    return pl.pallas_call(
-        _wave_kernel,
-        in_specs=[
-            pl.BlockSpec((m, 2), lambda: (0, 0)),
-            pl.BlockSpec((w,), lambda: (0,)),
-            pl.BlockSpec((w,), lambda: (0,)),
-            pl.BlockSpec((w, 2), lambda: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((m, 2), lambda: (0, 0)),
-            pl.BlockSpec((w, 2), lambda: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((m, 2), jnp.uint32),
-            jax.ShapeDtypeStruct((w, 2), jnp.uint32),
-        ],
-        interpret=interpret,
-    )(mem, addr.astype(jnp.int32), write.astype(jnp.int32), sval)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def wave_step(
-    mem: jax.Array,   # (M, 2) uint32 — f64 memory image bit patterns
-    addr: jax.Array,  # (W,) int32 flat addresses in [0, M)
-    write: jax.Array,  # (W,) int32 1 = valid store lane, 0 = load/pad
-    sval: jax.Array,  # (W, 2) uint32 — precomputed store value patterns
-    *,
-    interpret: bool = False,
-) -> tuple[jax.Array, jax.Array]:
-    """Execute one batched step; returns (new mem image, gathered rows).
-
-    Caller contract: every lane's address must be in [0, M) and no two
-    *write* lanes may share an address (WavePlan contract 5 — one
-    valid store per address per step). Non-write lanes never scatter
-    (they are redirected to the scratch row M-1), so load and pad
-    lanes may freely alias any address. Gathered rows are returned for
-    every lane against the pre-step image; the caller keeps only the
-    load lanes.
-    """
-    return _step_call(mem, addr, write, sval, interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@jax.jit
 def wave_loop(
     mem: jax.Array,    # (M, 2) uint32 — f64 memory image bit patterns
-    addrs: jax.Array,  # (S, W) int32 per-step flat addresses
-    writes: jax.Array,  # (S, W) int32 per-step write masks
+    addrs: jax.Array,  # (S, W) int32 per-step flat addresses in [0, M)
+    writes: jax.Array,  # (S, W) bool per-step valid-store lanes
     svals: jax.Array,  # (S, W, 2) uint32 per-step store value patterns
-    *,
-    interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """Execute S equal-width steps as one ``jax.lax.fori_loop``.
 
-    The per-step tables are precomputed on the host from the WavePlan's
-    step offsets (``kernels/wave_exec/ops.py`` stacks them per
-    segment); the loop body indexes them by step and chains the memory
-    image through the carry — no host round-trip between steps. Pad
+    Caller contract: every lane's address is in [0, M) and no two
+    *write* lanes of one step share an address (WavePlan contract 5 —
+    one valid store per address per step). Non-write lanes never
+    scatter to their own address (they are redirected to the scratch
+    row M-1), so load and pad lanes may freely alias any address. Pad
     steps (all lanes scratch, no writes) are no-ops, so the caller may
     pad S to a bucket size to bound compile count. Returns (final mem
-    image, (S, W, 2) gathered rows per step).
+    image, (S, W, 2) rows gathered per step against the pre-step image;
+    the caller keeps only the load lanes).
     """
+    scratch = mem.shape[0] - 1
 
     def body(s, carry):
         cur, vals = carry
-        nxt, v = _step_call(
-            cur,
-            jax.lax.dynamic_index_in_dim(addrs, s, 0, keepdims=False),
-            jax.lax.dynamic_index_in_dim(writes, s, 0, keepdims=False),
-            jax.lax.dynamic_index_in_dim(svals, s, 0, keepdims=False),
-            interpret,
+        addr, write = addrs[s], writes[s]
+        rows = cur.at[addr].get(mode="promise_in_bounds")  # pre-step
+        scat = jnp.where(write, addr, scratch)
+        nxt = cur.at[scat].set(
+            jnp.where(write[:, None], svals[s], cur[scratch]),
+            mode="promise_in_bounds",
         )
-        return nxt, jax.lax.dynamic_update_index_in_dim(vals, v, s, 0)
+        return nxt, vals.at[s].set(rows)
 
     vals0 = jnp.zeros(svals.shape, jnp.uint32)
     return jax.lax.fori_loop(0, addrs.shape[0], body, (mem, vals0))

@@ -1,4 +1,4 @@
-"""Wave-execution backend: drive a ``WavePlan`` through Pallas.
+"""Wave-execution backend: drive a ``WavePlan`` on the JAX device.
 
 ``run_plan`` is the hardware half of the DESIGN.md §2 split: the plan
 (from ``core/executor.build_wave_plan``) carries the batched-step
@@ -16,7 +16,8 @@ Execution is two-phase:
               buckets, stacked into segments of equal width, and each
               segment runs as **one** jitted ``wave_loop`` call — a
               ``jax.lax.fori_loop`` over the step tables chaining the
-              flat uint32-pair memory image through the carry. Final
+              flat uint32-pair memory image through the carry (XLA
+              gather/scatter, the same program on every platform). Final
               arrays are unpacked from the device image (and the
               per-step device gathers are checked bit-exact against
               the resolve phase under ``check=True``).
@@ -96,7 +97,6 @@ def _run(
     step_of: Optional[np.ndarray],
     n_steps: Optional[int],
     *,
-    interpret: bool,
     compute: str,
     check: bool,
     max_steps: Optional[int],
@@ -151,7 +151,7 @@ def _run(
         while ns_pad < ns:
             ns_pad *= 2
         addrs = np.full((ns_pad, wd), scratch, dtype=np.int32)
-        writes = np.zeros((ns_pad, wd), dtype=np.int32)
+        writes = np.zeros((ns_pad, wd), dtype=bool)
         svals = np.zeros((ns_pad, wd), dtype=np.float64)
         for j in range(ns):
             a, w, v, _ = rec[s0 + j]
@@ -161,7 +161,6 @@ def _run(
         mem_dev, vals = wave_loop(
             mem_dev, jnp.asarray(addrs), jnp.asarray(writes),
             jnp.asarray(_to_u32(svals).reshape(ns_pad, wd, 2)),
-            interpret=interpret,
         )
         if check:
             vals_h = np.asarray(vals)
@@ -171,6 +170,7 @@ def _run(
                     _from_u32(vals_h[j])[:len(a)], got,
                     err_msg="device gather diverged from resolve phase",
                 )
+    mem_dev.block_until_ready()
     t_device = time.perf_counter() - t0
 
     mem_out = _from_u32(np.asarray(mem_dev))
@@ -191,12 +191,11 @@ def run_plan(
     plan: execlib.WavePlan,
     arrays: dict[str, np.ndarray],
     *,
-    interpret: bool = True,
     compute: str = "host",
     check: bool = True,
     max_steps: Optional[int] = None,
 ) -> WaveExecResult:
-    """Execute a WavePlan step-parallel through the Pallas backend.
+    """Execute a WavePlan step-parallel on the default JAX device.
 
     ``compute="host"`` (default) evaluates the op-table closures in
     numpy — elementwise identical to the oracle, so final arrays are
@@ -207,14 +206,13 @@ def run_plan(
     request-exact against the plan's oracle reference streams during
     the resolve phase, then the device gathers and final image
     bit-exact against the resolve phase — leave on except when timing.
-    ``interpret`` runs the Pallas kernels in interpreter mode (the CPU
-    CI path); pass False on real TPU hardware.
+    The device phase is plain XLA, so the same compiled program runs on
+    the CPU backend (tests) and on a TPU; there is no interpreter mode.
     """
     assert compute in ("host", "jnp"), f"unknown compute {compute!r}"
     return _run(
         plan, arrays, None, None,
-        interpret=interpret, compute=compute, check=check,
-        max_steps=max_steps,
+        compute=compute, check=check, max_steps=max_steps,
     )
 
 
@@ -222,7 +220,6 @@ def run_sequential(
     plan: execlib.WavePlan,
     arrays: dict[str, np.ndarray],
     *,
-    interpret: bool = True,
     compute: str = "host",
     check: bool = False,
     max_steps: Optional[int] = None,
@@ -236,6 +233,5 @@ def run_sequential(
     n = plan.n_requests
     return _run(
         plan, arrays, np.arange(n, dtype=np.int64), n,
-        interpret=interpret, compute=compute, check=check,
-        max_steps=max_steps,
+        compute=compute, check=check, max_steps=max_steps,
     )

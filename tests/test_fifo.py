@@ -141,7 +141,7 @@ def test_wave_backends_differential(name):
         res_np = executor.execute(
             prog, _copies(arrays), params, fifo_depth=depth
         )
-        res_pl = wave_exec.run_plan(plan, arrays, interpret=True)
+        res_pl = wave_exec.run_plan(plan, arrays)
         res_sq = wave_exec.run_sequential(plan, arrays, check=True)
         assert res_pl.complete and res_sq.complete
         for k, v in oracle.items():

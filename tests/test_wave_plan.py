@@ -255,7 +255,7 @@ def test_floor_kernel_backends_differential(name):
             prog, {k: v.copy() for k, v in arrays.items()}, params,
             speculation=spec,
         )
-        res_pl = wave_exec.run_plan(plan, arrays, interpret=True)
+        res_pl = wave_exec.run_plan(plan, arrays)
         res_sq = wave_exec.run_sequential(plan, arrays, check=True)
         assert res_pl.complete and res_sq.complete
         for k in oracle:
