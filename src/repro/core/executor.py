@@ -62,13 +62,19 @@ pins any divergence to the first offending request.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 from typing import Optional
 
 import numpy as np
 
+from repro import trace
 from repro.core import config as cfglib
 from repro.core import loopir as ir
 from repro.core import optable as optablelib
+
+# numbers the calls of ``execute`` in this process (the ``call`` stat of
+# their ``repro.execute`` spans)
+_CALLS = itertools.count()
 
 
 @dataclasses.dataclass
@@ -326,45 +332,66 @@ def build_wave_plan(
         )
     fifo_depth = int(fifo_depth)
     params = params or {}
+    with trace.span("plan"):
+        return _plan(
+            program, arrays, params, trace_mode, speculation, predictor,
+            batch_waves, fifo_depth, symbolic_admission,
+        )
 
+
+def _plan(
+    program: ir.Program,
+    arrays: dict[str, np.ndarray],
+    params: dict[str, int],
+    trace_mode: str,
+    speculation: str,
+    predictor: str,
+    batch_waves: bool,
+    fifo_depth: int,
+    symbolic_admission: bool,
+) -> WavePlan:
+    """``build_wave_plan`` with its options resolved."""
     from repro.core import coarsen as coarsenlib
     from repro.core import dae as daelib
     from repro.core import fifo as fifolib
 
-    dae = daelib.decouple(program, speculation=speculation, predictor=predictor)
-    fifo_spec = None
-    if dae.fifo_edges:
-        if dae.spec:
-            raise NotImplementedError(
-                "cross-PE FIFO streaming cannot combine with speculative "
-                "AGUs (loss-of-decoupling PEs) in the wave executor"
-            )
-        fifo_spec = fifolib.analyze_program(program, dae)
-        fifolib.check_depth(fifo_spec, fifo_depth)
-    # the flat image and the op-table closures compute in f64; a
-    # narrower protected array would make the oracle round every store
-    # to the array dtype and the backends diverge in the last ulp —
-    # reject it up front instead of tripping a divergence assert deep
-    # in the wave loop (unprotected Read arrays may be any dtype)
-    for arr in sorted({op.array for op, _ in program.mem_ops()}):
-        if arrays[arr].dtype != np.float64:
-            raise ValueError(
-                f"wave executor requires float64 protected arrays: "
-                f"'{arr}' is {arrays[arr].dtype}"
-            )
-    # consumer stores reading streamed locals compile those to CDeps on
-    # the pseudo pop ops (optable stream_deps, DESIGN.md §11)
-    stream_deps: dict[str, dict[str, str]] = {}
-    if fifo_spec:
-        for op, _path in program.mem_ops():
-            if not op.is_store:
-                continue
-            ins = fifo_spec.in_edges.get(dae.op_to_pe[op.id], ())
-            if ins:
-                stream_deps[op.id] = {
-                    name: f"~pop:{eidx}" for eidx, name in ins
-                }
-    tables = optablelib.compile_store_tables(program, stream_deps or None)
+    with trace.span("plan.analyze"):
+        dae = daelib.decouple(
+            program, speculation=speculation, predictor=predictor
+        )
+        fifo_spec = None
+        if dae.fifo_edges:
+            if dae.spec:
+                raise NotImplementedError(
+                    "cross-PE FIFO streaming cannot combine with speculative "
+                    "AGUs (loss-of-decoupling PEs) in the wave executor"
+                )
+            fifo_spec = fifolib.analyze_program(program, dae)
+            fifolib.check_depth(fifo_spec, fifo_depth)
+        # the flat image and the op-table closures compute in f64; a
+        # narrower protected array would make the oracle round every store
+        # to the array dtype and the backends diverge in the last ulp —
+        # reject it up front instead of tripping a divergence assert deep
+        # in the wave loop (unprotected Read arrays may be any dtype)
+        for arr in sorted({op.array for op, _ in program.mem_ops()}):
+            if arrays[arr].dtype != np.float64:
+                raise ValueError(
+                    f"wave executor requires float64 protected arrays: "
+                    f"'{arr}' is {arrays[arr].dtype}"
+                )
+        # consumer stores reading streamed locals compile those to CDeps on
+        # the pseudo pop ops (optable stream_deps, DESIGN.md §11)
+        stream_deps: dict[str, dict[str, str]] = {}
+        if fifo_spec:
+            for op, _path in program.mem_ops():
+                if not op.is_store:
+                    continue
+                ins = fifo_spec.in_edges.get(dae.op_to_pe[op.id], ())
+                if ins:
+                    stream_deps[op.id] = {
+                        name: f"~pop:{eidx}" for eidx, name in ins
+                    }
+        tables = optablelib.compile_store_tables(program, stream_deps or None)
     aux_exprs = {
         op_id: t.env_exprs for op_id, t in tables.items() if t.env_exprs
     }
@@ -460,184 +487,195 @@ def build_wave_plan(
             if fifo_loop_hook is not None:
                 fifo_loop_hook(loop, phase, reader)
 
-    if dae.spec:
-        # speculative programs get the documented auto-reject
-        # (DESIGN.md §10) through the shared conversion site
-        from repro.core import speculate
+    with trace.span("plan.walk") as walk:
+        if dae.spec:
+            # speculative programs get the documented auto-reject
+            # (DESIGN.md §10) through the shared conversion site
+            from repro.core import speculate
 
-        speculate.interpret_hooked(
-            program, arrays, params, hook,
-            aux_exprs=aux_exprs, aux_hook=aux_hook,
-        )
-    else:
-        ir.interpret(
-            program, arrays, params, trace_hook=hook,
-            aux_exprs=aux_exprs, aux_hook=aux_hook, loop_hook=loop_hook,
-        )
+            speculate.interpret_hooked(
+                program, arrays, params, hook,
+                aux_exprs=aux_exprs, aux_hook=aux_hook,
+            )
+        else:
+            ir.interpret(
+                program, arrays, params, trace_hook=hook,
+                aux_exprs=aux_exprs, aux_hook=aux_hook, loop_hook=loop_hook,
+            )
+        walk.set(requests=n_real[0])
 
     if trace_mode != "interp":
-        req_op_l, req_addr_l, req_store_l = _trace_stream(
-            program, dae, arrays, params, trace_mode,
-            oracle_loads=load_streams if dae.spec else None,
-            predictor=predictor,
-        )
-        n_oracle = sum(len(v) for v in per_op_vv.values())
-        assert n_oracle == len(req_op_l), (
-            f"trace stream has {len(req_op_l)} requests, oracle walk "
-            f"{n_oracle} — trace compiler divergence"
-        )
+        with trace.span("plan.trace"):
+            req_op_l, req_addr_l, req_store_l = _trace_stream(
+                program, dae, arrays, params, trace_mode,
+                oracle_loads=load_streams if dae.spec else None,
+                predictor=predictor,
+            )
+            n_oracle = sum(len(v) for v in per_op_vv.values())
+            assert n_oracle == len(req_op_l), (
+                f"trace stream has {len(req_op_l)} requests, oracle walk "
+                f"{n_oracle} — trace compiler divergence"
+            )
     else:
         req_op_l = [r[0] for r in interp_stream]
         req_addr_l = [r[1] for r in interp_stream]
         req_store_l = [r[2] for r in interp_stream]
 
-    op_ids = [op.id for op, _ in program.mem_ops()]
-    op_array = {op.id: op.array for op, _ in program.mem_ops()}
-    op_is_store = {op.id: op.is_store for op, _ in program.mem_ops()}
+    with trace.span("plan.streams"):
+        op_ids = [op.id for op, _ in program.mem_ops()]
+        op_array = {op.id: op.array for op, _ in program.mem_ops()}
+        op_is_store = {op.id: op.is_store for op, _ in program.mem_ops()}
 
-    # merge the FIFO token events into the request stream as pseudo
-    # requests on the edge's circular slots (module docstring) — after
-    # the trace-count assert, which covers real requests only
-    push_k: dict[int, int] = {}
-    if fifo_events:
-        pop_k: dict[int, int] = {}
-        m_op: list[str] = []
-        m_addr: list[int] = []
-        m_store: list[bool] = []
-        ev = 0
-        for pos in range(len(req_op_l) + 1):
-            while ev < len(fifo_events) and fifo_events[ev][0] == pos:
-                _p, kind, eidx, value = fifo_events[ev]
-                ev += 1
-                if kind == "push":
-                    o = f"~push:{eidx}"
-                    k = push_k.get(eidx, 0)
-                    push_k[eidx] = k + 1
-                    m_store.append(True)
-                else:
-                    o = f"~pop:{eidx}"
-                    k = pop_k.get(eidx, 0)
-                    pop_k[eidx] = k + 1
-                    m_store.append(False)
-                m_op.append(o)
-                m_addr.append(k % fifo_depth)
-                per_op_vv.setdefault(o, []).append((True, value))
-            if pos < len(req_op_l):
-                m_op.append(req_op_l[pos])
-                m_addr.append(req_addr_l[pos])
-                m_store.append(req_store_l[pos])
-        req_op_l, req_addr_l, req_store_l = m_op, m_addr, m_store
-    if fifo_spec:
-        for e in fifo_spec.edges:
-            for o, st in ((f"~push:{e.idx}", True), (f"~pop:{e.idx}", False)):
-                op_ids.append(o)
-                op_array[o] = f"~fifo:{e.idx}"
-                op_is_store[o] = st
-            po = f"~push:{e.idx}"
-            tables[po] = optablelib.StoreTable(
-                op_id=po, array=f"~fifo:{e.idx}", deps=(),
-                env_exprs=(ir.Local(e.local),),  # descriptive; slot 0 is
-                value=optablelib.CEnv(0),        # the captured token
-                guard=None, frozen_reads=(),
-            )
-            dep_rows[po] = {}
+        # merge the FIFO token events into the request stream as pseudo
+        # requests on the edge's circular slots (module docstring) — after
+        # the trace-count assert, which covers real requests only
+        push_k: dict[int, int] = {}
+        if fifo_events:
+            pop_k: dict[int, int] = {}
+            m_op: list[str] = []
+            m_addr: list[int] = []
+            m_store: list[bool] = []
+            ev = 0
+            for pos in range(len(req_op_l) + 1):
+                while ev < len(fifo_events) and fifo_events[ev][0] == pos:
+                    _p, kind, eidx, value = fifo_events[ev]
+                    ev += 1
+                    if kind == "push":
+                        o = f"~push:{eidx}"
+                        k = push_k.get(eidx, 0)
+                        push_k[eidx] = k + 1
+                        m_store.append(True)
+                    else:
+                        o = f"~pop:{eidx}"
+                        k = pop_k.get(eidx, 0)
+                        pop_k[eidx] = k + 1
+                        m_store.append(False)
+                    m_op.append(o)
+                    m_addr.append(k % fifo_depth)
+                    per_op_vv.setdefault(o, []).append((True, value))
+                if pos < len(req_op_l):
+                    m_op.append(req_op_l[pos])
+                    m_addr.append(req_addr_l[pos])
+                    m_store.append(req_store_l[pos])
+            req_op_l, req_addr_l, req_store_l = m_op, m_addr, m_store
+        if fifo_spec:
+            for e in fifo_spec.edges:
+                for o, st in ((f"~push:{e.idx}", True),
+                              (f"~pop:{e.idx}", False)):
+                    op_ids.append(o)
+                    op_array[o] = f"~fifo:{e.idx}"
+                    op_is_store[o] = st
+                po = f"~push:{e.idx}"
+                tables[po] = optablelib.StoreTable(
+                    op_id=po, array=f"~fifo:{e.idx}", deps=(),
+                    env_exprs=(ir.Local(e.local),),  # descriptive; slot 0 is
+                    value=optablelib.CEnv(0),        # the captured token
+                    guard=None, frozen_reads=(),
+                )
+                dep_rows[po] = {}
 
-    n = len(req_op_l)
-    op_index = {o: i for i, o in enumerate(op_ids)}
+        n = len(req_op_l)
+        op_index = {o: i for i, o in enumerate(op_ids)}
 
-    req_op = np.fromiter(
-        (op_index[o] for o in req_op_l), dtype=np.int32, count=n
-    )
-    req_addr = np.asarray(req_addr_l, dtype=np.int64) if n else np.zeros(
-        0, dtype=np.int64
-    )
-    req_store = np.asarray(req_store_l, dtype=bool) if n else np.zeros(
-        0, dtype=bool
-    )
+        req_op = np.fromiter(
+            (op_index[o] for o in req_op_l), dtype=np.int32, count=n
+        )
+        req_addr = np.asarray(req_addr_l, dtype=np.int64) if n else np.zeros(
+            0, dtype=np.int64
+        )
+        req_store = np.asarray(req_store_l, dtype=bool) if n else np.zeros(
+            0, dtype=bool
+        )
 
-    # per-op ordinal + the (valid, value) reference streams, by ordinal
-    req_ordinal = np.zeros(n, dtype=np.int64)
-    req_valid = np.zeros(n, dtype=bool)
-    req_value = np.full(n, np.nan, dtype=np.float64)
-    taken: dict[str, int] = {}
-    for i in range(n):
-        o = req_op_l[i]
-        k = taken.get(o, 0)
-        taken[o] = k + 1
-        req_ordinal[i] = k
-        valid, value = per_op_vv[o][k]
-        req_valid[i] = valid
-        if value is not None:
-            req_value[i] = value
+        # per-op ordinal + the (valid, value) reference streams, by ordinal
+        req_ordinal = np.zeros(n, dtype=np.int64)
+        req_valid = np.zeros(n, dtype=bool)
+        req_value = np.full(n, np.nan, dtype=np.float64)
+        taken: dict[str, int] = {}
+        for i in range(n):
+            o = req_op_l[i]
+            k = taken.get(o, 0)
+            taken[o] = k + 1
+            req_ordinal[i] = k
+            valid, value = per_op_vv[o][k]
+            req_valid[i] = valid
+            if value is not None:
+                req_value[i] = value
 
     # --- pass 2: wave assignment (one program-order sweep) ---------------
-    waves = np.zeros(n, dtype=np.int64)
-    # per (array, addr): wave of last store; max wave of loads since it
-    last_store_wave: dict[tuple[str, int], int] = {}
-    loads_since_store: dict[tuple[str, int], int] = {}
-    # per load op: wave of its k-th request (appended in program order,
-    # so list position == ordinal) — the exact per-(PE, dep-edge)
-    # dataflow inputs a store's wave is computed from
-    wave_of_load: dict[str, list[int]] = {}
-    # per request: max wave over its feeding loads (-1 for loads and
-    # dep-free stores) — feeds the wave-batching admission rule
-    feed_max = np.full(n, -1, dtype=np.int64)
+    with trace.span("plan.waves") as sweep:
+        waves = np.zeros(n, dtype=np.int64)
+        # per (array, addr): wave of last store; max wave of loads since it
+        last_store_wave: dict[tuple[str, int], int] = {}
+        loads_since_store: dict[tuple[str, int], int] = {}
+        # per load op: wave of its k-th request (appended in program order,
+        # so list position == ordinal) — the exact per-(PE, dep-edge)
+        # dataflow inputs a store's wave is computed from
+        wave_of_load: dict[str, list[int]] = {}
+        # per request: max wave over its feeding loads (-1 for loads and
+        # dep-free stores) — feeds the wave-batching admission rule
+        feed_max = np.full(n, -1, dtype=np.int64)
 
-    # FIFO pushes carry a CU local: they must land strictly after every
-    # load (and pop) of the producer PE seen so far — tracked as a
-    # running per-PE wave frontier over the load-like requests
-    pe_frontier: dict[int, int] = {}
-    push_pe: dict[str, int] = {}
-    pop_pe: dict[str, int] = {}
-    if fifo_spec:
-        for e in fifo_spec.edges:
-            push_pe[f"~push:{e.idx}"] = e.prod_pe
-            pop_pe[f"~pop:{e.idx}"] = e.cons_pe
+        # FIFO pushes carry a CU local: they must land strictly after every
+        # load (and pop) of the producer PE seen so far — tracked as a
+        # running per-PE wave frontier over the load-like requests
+        pe_frontier: dict[int, int] = {}
+        push_pe: dict[str, int] = {}
+        pop_pe: dict[str, int] = {}
+        if fifo_spec:
+            for e in fifo_spec.edges:
+                push_pe[f"~push:{e.idx}"] = e.prod_pe
+                pop_pe[f"~pop:{e.idx}"] = e.cons_pe
 
-    for i in range(n):
-        o = req_op_l[i]
-        key = (op_array[o], req_addr_l[i])
-        if req_store[i]:
-            # WAW: after last store; WAR: after every load since it;
-            # dataflow: after exactly the load requests feeding this
-            # store's value/guard (dep maps, contract 3) — invalid §6
-            # stores included, their *guard* still reads those loads
-            fm = -1
-            k = req_ordinal[i]
-            for ld in tables[o].deps:
-                m = dep_rows[o][ld][k]
-                if m >= 0:
-                    lw = wave_of_load[ld][m]
-                    if lw > fm:
-                        fm = lw
-            ppe = push_pe.get(o)
-            if ppe is not None:
-                fm = max(fm, pe_frontier.get(ppe, -1))
-            feed_max[i] = fm
-            w = max(
-                last_store_wave.get(key, -1) + 1,
-                loads_since_store.get(key, -1) + 1,
-                fm + 1,
-            )
-            if req_valid[i]:
-                last_store_wave[key] = w
-                loads_since_store[key] = -1
+        for i in range(n):
+            o = req_op_l[i]
+            key = (op_array[o], req_addr_l[i])
+            if req_store[i]:
+                # WAW: after last store; WAR: after every load since it;
+                # dataflow: after exactly the load requests feeding this
+                # store's value/guard (dep maps, contract 3) — invalid §6
+                # stores included, their *guard* still reads those loads
+                fm = -1
+                k = req_ordinal[i]
+                for ld in tables[o].deps:
+                    m = dep_rows[o][ld][k]
+                    if m >= 0:
+                        lw = wave_of_load[ld][m]
+                        if lw > fm:
+                            fm = lw
+                ppe = push_pe.get(o)
+                if ppe is not None:
+                    fm = max(fm, pe_frontier.get(ppe, -1))
+                feed_max[i] = fm
+                w = max(
+                    last_store_wave.get(key, -1) + 1,
+                    loads_since_store.get(key, -1) + 1,
+                    fm + 1,
+                )
+                if req_valid[i]:
+                    last_store_wave[key] = w
+                    loads_since_store[key] = -1
+                else:
+                    # §6: invalid stores occupy a wave slot (they update the
+                    # frontier in hardware) but have no memory effect
+                    last_store_wave[key] = max(
+                        last_store_wave.get(key, -1), w
+                    )
             else:
-                # §6: invalid stores occupy a wave slot (they update the
-                # frontier in hardware) but have no memory effect
-                last_store_wave[key] = max(last_store_wave.get(key, -1), w)
-        else:
-            # RAW: after the last store to this address
-            w = last_store_wave.get(key, -1) + 1
-            loads_since_store[key] = max(loads_since_store.get(key, -1), w)
-            wave_of_load.setdefault(o, []).append(w)
-            if fifo_spec:
-                pe_of = pop_pe.get(o, dae.op_to_pe.get(o))
-                if pe_of is not None and w > pe_frontier.get(pe_of, -1):
-                    pe_frontier[pe_of] = w
-        waves[i] = w
+                # RAW: after the last store to this address
+                w = last_store_wave.get(key, -1) + 1
+                loads_since_store[key] = max(
+                    loads_since_store.get(key, -1), w
+                )
+                wave_of_load.setdefault(o, []).append(w)
+                if fifo_spec:
+                    pe_of = pop_pe.get(o, dae.op_to_pe.get(o))
+                    if pe_of is not None and w > pe_frontier.get(pe_of, -1):
+                        pe_frontier[pe_of] = w
+            waves[i] = w
 
-    n_waves = int(waves.max()) + 1 if n else 0
+        n_waves = int(waves.max()) + 1 if n else 0
+        sweep.set(waves=n_waves)
 
     # --- wave coarsening: batch conflict-free waves into steps -----------
     # (needs flat addresses — computed below — so steps are assigned
@@ -703,7 +741,8 @@ def build_wave_plan(
     if symbolic_admission:
         from repro.analysis import deps as depslib
 
-        free = depslib.symbolically_free_ops(program)
+        with trace.span("plan.analyze"):
+            free = depslib.symbolically_free_ops(program)
         sym_ops = tuple(sorted(o for o, ok in free.items() if ok))
         free_arr = np.asarray(
             [free.get(o, False) for o in op_ids], dtype=bool
@@ -712,9 +751,11 @@ def build_wave_plan(
         n_sym = int(sym_free.sum())
 
     if batch_waves:
-        step_of_wave, n_steps = coarsenlib.batch_conflict_free_waves(
-            waves, req_flat, req_store, feed_max, symbolic_free=sym_free,
-        )
+        with trace.span("plan.coarsen") as coarsen:
+            step_of_wave, n_steps = coarsenlib.batch_conflict_free_waves(
+                waves, req_flat, req_store, feed_max, symbolic_free=sym_free,
+            )
+            coarsen.set(steps=int(n_steps))
         req_step = step_of_wave[waves] if n else waves.copy()
     else:
         req_step, n_steps = waves.copy(), n_waves
@@ -1099,21 +1140,23 @@ def execute(
         symbolic_admission=symbolic_admission, validate_hints=validate_hints,
     )
     backend, validate_hints = cfg.backend, cfg.validate_hints
-    plan = build_wave_plan(
-        program, arrays, params, fifo_depth=fifo_depth, config=cfg,
-    )
-    if validate_hints:
-        validate_plan_hints(plan)
-    run = None
-    if backend == "numpy":
-        out = _replay_numpy(plan, arrays)
-    elif backend == "pallas":
-        from repro.kernels import wave_exec
+    with trace.span("execute", call=next(_CALLS), backend=backend) as call:
+        plan = build_wave_plan(
+            program, arrays, params, fifo_depth=fifo_depth, config=cfg,
+        )
+        call.set(n_requests=plan.n_requests)
+        if validate_hints:
+            validate_plan_hints(plan)
+        run = None
+        if backend == "numpy":
+            out = _replay_numpy(plan, arrays)
+        elif backend == "pallas":
+            from repro.kernels import wave_exec
 
-        run = wave_exec.run_plan(plan, arrays)
-        out = run.arrays
-    else:
-        raise ValueError(f"unknown backend {backend!r}")
+            run = wave_exec.run_plan(plan, arrays)
+            out = run.arrays
+        else:
+            raise ValueError(f"unknown backend {backend!r}")
     return ExecResult(
         arrays=out, stats=plan.stats, waves=plan.req_wave, plan=plan,
         run=run,

@@ -25,9 +25,21 @@ Execution is two-phase:
 The split mirrors what the DU is: the resolve phase *disambiguates*
 (and owns every divergence check); the device phase only *moves* —
 which is why the whole memory schedule compiles to O(segments) kernel
-launches instead of one per step, and why step count no longer
-dominates wall-clock (ROADMAP item 1). Pad lanes target a scratch row
-past the image; pad steps are no-ops (see ``kernel.py``).
+launches instead of one per step. The device phase's wall time then
+follows the segment count, and it is host work, not kernel time: about
+2.5 ms a segment on a TPU v5e against 0.03 ms of ``wave_loop``, for the
+Python padding of the step tables, the transfers, the dispatch and the
+``check=True`` copy back. Pad lanes target a scratch row past the
+image; pad steps are no-ops (see ``kernel.py``).
+
+Spans (``repro.trace``): ``repro.resolve`` and ``repro.device`` bound
+the two phases (``resolve_s`` and ``device_s`` are their seconds);
+inside the device phase each segment has ``repro.device.pack`` (the
+padded tables), ``repro.device.launch`` (transfers and the
+``wave_loop`` call; ``new_shape`` is 1 where its shape is new to the
+process, so it compiles), and under ``check`` ``repro.device.wait``
+and ``repro.device.check`` (the copy back and compare); the final
+image copy, compare and unpack are ``repro.unpack``.
 
 ``run_sequential`` executes the same plan one request per step — the
 paper's non-fused baseline on identical hardware (a single bucket-8
@@ -46,16 +58,19 @@ against the oracle.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Optional
 
 import numpy as np
 
+from repro import trace
 from repro.core import executor as execlib
 
 __all__ = ["run_plan", "run_sequential", "WaveExecResult"]
 
 _MIN_BUCKET = 8
+# (padded steps, width, image rows) of every segment launched in this
+# process: a key not yet in it makes its launch trace and compile
+_SHAPES_SEEN: set = set()
 
 
 @dataclasses.dataclass
@@ -67,8 +82,8 @@ class WaveExecResult:
     n_steps: int  # executed gather→scatter steps (pad steps excluded)
     elapsed: float  # seconds: resolve + device phases
     complete: bool  # False when max_steps truncated the run
-    resolve_s: float = 0.0  # host resolution (op tables + checks)
-    device_s: float = 0.0  # segmented wave_loop execution
+    resolve_s: float = 0.0  # seconds of the repro.resolve span
+    device_s: float = 0.0  # seconds of the repro.device span
     n_segments: int = 0  # wave_loop launches (fori_loop calls)
 
 
@@ -124,66 +139,86 @@ def _run(
         rec.append((flat_addr, write, sval, got))
         return got
 
-    t0 = time.perf_counter()
-    steps, complete = execlib.drive_plan(
-        plan, mem_step, frozen=arrays, step_of=step_of, n_steps=n_steps,
-        lib="np" if compute == "host" else "jnp", check=check,
-        max_steps=max_steps,
-    )
-    t_resolve = time.perf_counter() - t0
+    with trace.span("resolve", steps=int(
+            plan.stats.n_steps if n_steps is None else n_steps)) as resolve:
+        steps, complete = execlib.drive_plan(
+            plan, mem_step, frozen=arrays, step_of=step_of, n_steps=n_steps,
+            lib="np" if compute == "host" else "jnp", check=check,
+            max_steps=max_steps,
+        )
 
     # --- device phase: segments of equal-width steps, one wave_loop each -
-    t0 = time.perf_counter()
-    mem_dev = jnp.asarray(_to_u32(mem_f64))
-    widths = [_bucket(len(a)) for a, _, _, _ in rec]
-    segments: list[tuple[int, int]] = []  # (start step, end step)
-    for s, wd in enumerate(widths):
-        if segments and widths[segments[-1][0]] == wd:
-            segments[-1] = (segments[-1][0], s + 1)
-        else:
-            segments.append((s, s + 1))
-    for s0, s1 in segments:
-        wd = widths[s0]
-        ns = s1 - s0
-        # pad the segment's step count to a power of two as well (pad
-        # steps are no-ops) so compile count is O(log steps · log width)
-        ns_pad = 1
-        while ns_pad < ns:
-            ns_pad *= 2
-        addrs = np.full((ns_pad, wd), scratch, dtype=np.int32)
-        writes = np.zeros((ns_pad, wd), dtype=bool)
-        svals = np.zeros((ns_pad, wd), dtype=np.float64)
-        for j in range(ns):
-            a, w, v, _ = rec[s0 + j]
-            addrs[j, :len(a)] = a
-            writes[j, :len(a)] = w
-            svals[j, :len(a)] = v
-        mem_dev, vals = wave_loop(
-            mem_dev, jnp.asarray(addrs), jnp.asarray(writes),
-            jnp.asarray(_to_u32(svals).reshape(ns_pad, wd, 2)),
-        )
-        if check:
-            vals_h = np.asarray(vals)
-            for j in range(ns):
-                a, _, _, got = rec[s0 + j]
-                np.testing.assert_array_equal(
-                    _from_u32(vals_h[j])[:len(a)], got,
-                    err_msg="device gather diverged from resolve phase",
+    with trace.span("device", h2d_bytes=mem_f64.nbytes) as device:
+        mem_dev = jnp.asarray(_to_u32(mem_f64))
+        widths = [_bucket(len(a)) for a, _, _, _ in rec]
+        segments: list[tuple[int, int]] = []  # (start step, end step)
+        for s, wd in enumerate(widths):
+            if segments and widths[segments[-1][0]] == wd:
+                segments[-1] = (segments[-1][0], s + 1)
+            else:
+                segments.append((s, s + 1))
+        device.set(segments=len(segments))
+        for s0, s1 in segments:
+            wd = widths[s0]
+            ns = s1 - s0
+            # pad the segment's step count to a power of two as well (pad
+            # steps are no-ops) so compile count is O(log steps · log width)
+            ns_pad = 1
+            while ns_pad < ns:
+                ns_pad *= 2
+            with trace.span("device.pack", steps=ns, steps_pad=ns_pad,
+                            width=wd):
+                addrs = np.full((ns_pad, wd), scratch, dtype=np.int32)
+                writes = np.zeros((ns_pad, wd), dtype=bool)
+                svals = np.zeros((ns_pad, wd), dtype=np.float64)
+                for j in range(ns):
+                    a, w, v, _ = rec[s0 + j]
+                    addrs[j, :len(a)] = a
+                    writes[j, :len(a)] = w
+                    svals[j, :len(a)] = v
+            key = (ns_pad, wd, len(mem_f64))
+            with trace.span(
+                "device.launch", new_shape=int(key not in _SHAPES_SEEN),
+                h2d_bytes=addrs.nbytes + writes.nbytes + svals.nbytes,
+            ):
+                _SHAPES_SEEN.add(key)
+                mem_dev, vals = wave_loop(
+                    mem_dev, jnp.asarray(addrs), jnp.asarray(writes),
+                    jnp.asarray(_to_u32(svals).reshape(ns_pad, wd, 2)),
                 )
-    mem_dev.block_until_ready()
-    t_device = time.perf_counter() - t0
+                if check:
+                    # the copy back queues behind the kernel now, so the
+                    # wait below costs no second trip to the host (about
+                    # 0.07 ms a segment on a TPU v5e)
+                    vals.copy_to_host_async()
+            if check:
+                with trace.span("device.wait"):
+                    vals.block_until_ready()
+                with trace.span("device.check", d2h_bytes=svals.nbytes):
+                    vals_h = np.asarray(vals)
+                    for j in range(ns):
+                        a, _, _, got = rec[s0 + j]
+                        np.testing.assert_array_equal(
+                            _from_u32(vals_h[j])[:len(a)], got,
+                            err_msg="device gather diverged from resolve "
+                            "phase",
+                        )
+        with trace.span("device.wait"):
+            mem_dev.block_until_ready()
 
-    mem_out = _from_u32(np.asarray(mem_dev))
-    if check:
-        np.testing.assert_array_equal(
-            mem_out[:plan.mem_size], host_mem[:plan.mem_size],
-            err_msg="device image diverged from resolve phase",
-        )
-    out = execlib.unpack_image(plan, mem_out, arrays)
+    with trace.span("unpack", d2h_bytes=mem_f64.nbytes):
+        mem_out = _from_u32(np.asarray(mem_dev))
+        if check:
+            np.testing.assert_array_equal(
+                mem_out[:plan.mem_size], host_mem[:plan.mem_size],
+                err_msg="device image diverged from resolve phase",
+            )
+        out = execlib.unpack_image(plan, mem_out, arrays)
     return WaveExecResult(
         arrays=out, stats=plan.stats, n_steps=steps,
-        elapsed=t_resolve + t_device, complete=complete,
-        resolve_s=t_resolve, device_s=t_device, n_segments=len(segments),
+        elapsed=resolve.seconds + device.seconds, complete=complete,
+        resolve_s=resolve.seconds, device_s=device.seconds,
+        n_segments=len(segments),
     )
 
 
