@@ -63,7 +63,7 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
-from typing import Optional
+from typing import Callable, Optional
 
 import numpy as np
 
@@ -339,6 +339,27 @@ def build_wave_plan(
         )
 
 
+# generated oracle walks (loopir.compile_walk), by program fingerprint,
+# op-table operands and loop hook; None where the program keeps the
+# interpreter
+_WALKS: dict[tuple, Optional[Callable]] = {}
+_WALKS_MAX = 256
+
+
+def _compiled_walk(program, aux_exprs, with_loop_hook):
+    """(the program's generated walk or None, whether this call made
+    it)."""
+    key = (program.fingerprint(), tuple(sorted(aux_exprs.items())),
+           with_loop_hook)
+    if key in _WALKS:
+        return _WALKS[key], False
+    walker = ir.compile_walk(program, aux_exprs, with_loop_hook)
+    if len(_WALKS) >= _WALKS_MAX:
+        del _WALKS[next(iter(_WALKS))]
+    _WALKS[key] = walker
+    return walker, walker is not None
+
+
 def _plan(
     program: ir.Program,
     arrays: dict[str, np.ndarray],
@@ -488,6 +509,7 @@ def _plan(
                 fifo_loop_hook(loop, phase, reader)
 
     with trace.span("plan.walk") as walk:
+        compiled, compiles = False, False
         if dae.spec:
             # speculative programs get the documented auto-reject
             # (DESIGN.md §10) through the shared conversion site
@@ -498,11 +520,21 @@ def _plan(
                 aux_exprs=aux_exprs, aux_hook=aux_hook,
             )
         else:
-            ir.interpret(
-                program, arrays, params, trace_hook=hook,
-                aux_exprs=aux_exprs, aux_hook=aux_hook, loop_hook=loop_hook,
+            walker, compiles = _compiled_walk(
+                program, aux_exprs, loop_hook is not None
             )
-        walk.set(requests=n_real[0])
+            if walker is not None:
+                compiled = walker(
+                    program, arrays, params, hook, aux_hook, loop_hook,
+                ) is not None
+            if not compiled:
+                ir.interpret(
+                    program, arrays, params, trace_hook=hook,
+                    aux_exprs=aux_exprs, aux_hook=aux_hook,
+                    loop_hook=loop_hook,
+                )
+        walk.set(requests=n_real[0], compiled=int(compiled),
+                 walk_compiles=int(compiles))
 
     if trace_mode != "interp":
         with trace.span("plan.trace"):

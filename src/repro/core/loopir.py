@@ -338,8 +338,12 @@ class Program:
         sessions (``repr``/``hash`` of nested dataclasses are not stable
         enough to key an on-disk cache). Array *contents* and parameter
         *values* are deliberately excluded: the DSE result cache
-        (``repro.dse.cache``) hashes those separately.
+        (``repro.dse.cache``) hashes those separately. Computed once per
+        program object (the program is immutable).
         """
+        cached = self.__dict__.get("_fingerprint")
+        if cached is not None:
+            return cached
         import hashlib
 
         h = hashlib.sha256()
@@ -369,7 +373,8 @@ class Program:
                 raise TypeError(f"cannot fingerprint {node!r}")
 
         enc(self)
-        return h.hexdigest()
+        object.__setattr__(self, "_fingerprint", h.hexdigest())
+        return self._fingerprint
 
     def static_positions(self) -> tuple[dict[int, int], dict[str, int]]:
         """(loop object id -> index in parent body, op id -> index in its
@@ -580,3 +585,386 @@ def interpret(
     for lp in program.loops:
         run_loop(lp, top, {})
     return arrays
+
+
+# ---------------------------------------------------------------------------
+# The oracle walk compiled to Python source
+# ---------------------------------------------------------------------------
+
+_CMP_OPS = frozenset(("<", "<=", ">", ">=", "==", "!="))
+_ARITH_OPS = frozenset(("+", "-", "*", "//", "%"))
+
+
+class _Uncompilable(Exception):
+    """The program needs ``interpret``'s run-time checks (see
+    ``compile_walk``)."""
+
+
+def _loops_in_order(program: Program) -> tuple:
+    """Every Loop of the program, outermost first, in program order."""
+    out: list = []
+
+    def walk(stmts):
+        for s in stmts:
+            if isinstance(s, Loop):
+                out.append(s)
+                walk(s.body)
+
+    walk(program.loops)
+    return tuple(out)
+
+
+class _WalkSource:
+    """One pass of the code generator behind ``compile_walk``.
+
+    Names resolve statically, scope by scope, exactly as ``_Env`` and the
+    ``loadvals`` dicts resolve them at run time: each binding (a loop
+    var, an ivar, a local defined in one scope, a Load's value) becomes
+    one Python local. Alongside it tracks, per binding, whether its
+    value has the same Python type under both walkers (``interpret``
+    reads numpy scalars where this walker reads list items) and whether
+    it may be a comparison's result; ``disagree`` and ``boolish`` are
+    the bindings known so far not to agree or to be boolean, and a pass
+    that learns more asks for another (``grown``).
+    """
+
+    def __init__(self, program, aux_exprs, with_loop_hook, disagree, boolish):
+        self.aux = aux_exprs
+        self.hooked = with_loop_hook
+        self.disagree = disagree
+        self.boolish = boolish
+        self.grown = False
+        self.lines: list[str] = []
+        self.consts: list = []
+        self.arrays: dict[str, int] = {}
+        self.params: dict[str, int] = {}
+        self.stored: set[str] = set()
+        self.n_bind = 0
+        self.n_loop = 0
+        self.uns: dict[str, str] = {}
+        # loop vars no SetLocal assigns: Python ints, which index and
+        # address without int()
+        self.counters: set[int] = set()
+        self.set_names = {
+            s.name for lp in _loops_in_order(program) for s in lp.body
+            if isinstance(s, SetLocal)
+        }
+        for lp in program.loops:
+            if not isinstance(lp, Loop):
+                raise _Uncompilable(f"top-level {lp!r} is not a Loop")
+            self.loop(lp, [{}], {}, 1)
+
+    # -- bindings and values ------------------------------------------------
+
+    def bind(self) -> int:
+        self.n_bind += 1
+        return self.n_bind - 1
+
+    def assign(self, b: int, agree: bool, boolish: bool):
+        if not agree and b not in self.disagree:
+            self.disagree.add(b)
+            self.grown = True
+        if boolish and b not in self.boolish:
+            self.boolish.add(b)
+            self.grown = True
+
+    def value(self, b: int):
+        return (f"b{b}", b not in self.disagree, b in self.boolish,
+                b in self.counters)
+
+    @staticmethod
+    def resolve(scope, name):
+        for frame in reversed(scope):
+            if name in frame:
+                return frame[name]
+        raise _Uncompilable(f"'{name}' is not defined where it is read")
+
+    def const(self, v) -> str:
+        self.consts.append(v)
+        return f"c{len(self.consts) - 1}"
+
+    def array(self, name) -> str:
+        return f"A{self.arrays.setdefault(name, len(self.arrays))}"
+
+    @staticmethod
+    def as_int(src, isint) -> str:
+        return src if isint else f"int_({src})"
+
+    # -- expressions ------------------------------------------------------------
+
+    def expr(self, e, scope, loads):
+        """(source, agrees, may be a comparison's result, is a Python
+        int) of ``e``."""
+        if isinstance(e, Const):
+            return self.const(e.v), True, False, type(e.v) is int
+        if isinstance(e, Param):
+            j = self.params.setdefault(e.name, len(self.params))
+            return f"p{j}", True, False, False
+        if isinstance(e, (Var, Local)):
+            return self.value(self.resolve(scope, e.name))
+        if isinstance(e, LoadVal):
+            if e.load_id not in loads:
+                raise _Uncompilable(f"LoadVal('{e.load_id}') read before its Load")
+            return self.value(loads[e.load_id])
+        if isinstance(e, Read):
+            idx = self.as_int(*self.expr(e.index, scope, loads)[::3])
+            return f"{self.array(e.array)}[{idx}]", False, False, False
+        if isinstance(e, Bin):
+            a, aa, ba, ia = self.expr(e.a, scope, loads)
+            b, ab, bb, ib = self.expr(e.b, scope, loads)
+            if e.op in _CMP_OPS:
+                return f"({a} {e.op} {b})", aa and ab, True, False
+            if e.op in ("min", "max"):
+                return f"{e.op}_({a}, {b})", aa and ab, ba or bb, ia and ib
+            if e.op not in _ARITH_OPS:
+                raise _Uncompilable(f"unknown binop {e.op}")
+            self.arith(aa, ba, ab, bb)
+            if e.op in ("//", "%") and not (aa and ab) and not (
+                isinstance(e.b, Const) and type(e.b.v) in (int, float) and e.b.v
+            ):
+                # a zero divisor raises on Python numbers and gives inf
+                # or nan on numpy scalars
+                raise _Uncompilable(f"'{e.op}' by a value that may be 0")
+            return f"({a} {e.op} {b})", aa and ab, False, ia and ib
+        if isinstance(e, Un):
+            a, aa, ba, ia = self.expr(e.a, scope, loads)
+            if e.op not in _UN_FNS:
+                raise _Uncompilable(f"unknown unop {e.op}")
+            self.arith(aa, ba, True, False)
+            if e.op == "neg":
+                return f"(-{a})", aa, False, ia
+            fn = self.uns.setdefault(e.op, f"u{len(self.uns)}")
+            return f"{fn}({a})", True, False, False
+        raise _Uncompilable(f"cannot eval {e!r}")
+
+    @staticmethod
+    def arith(aa, ba, ab, bb):
+        # arithmetic on numpy booleans is logical, on Python's integral
+        if (ba and not aa) or (bb and not ab):
+            raise _Uncompilable("arithmetic on a comparison's result")
+
+    # -- statements ------------------------------------------------------------
+
+    def emit(self, depth, line):
+        self.lines.append("    " * depth + line)
+
+    def reader(self, scope) -> str:
+        names = {}
+        for frame in scope:
+            names.update(frame)
+        items = ", ".join(f"{n!r}: b{b}" for n, b in sorted(names.items()))
+        return f"{{{items}}}.__getitem__"
+
+    def loop(self, lp, scope, loads, d):
+        k = self.n_loop
+        self.n_loop += 1
+        if self.hooked:
+            self.emit(d, f"loop_hook(L{k}, 'enter', {self.reader(scope)})")
+        ivars: dict[str, int] = {}
+        for iv in lp.ivars:
+            src, agree, boolish, _ = self.expr(iv.init, scope, loads)
+            b = ivars.setdefault(iv.name, self.bind())
+            self.assign(b, agree, boolish)
+            self.emit(d, f"b{b} = {src}")
+        trip = self.as_int(*self.expr(lp.trip, scope, loads)[::3])
+        var = self.bind()
+        if lp.var not in self.set_names:
+            self.counters.add(var)
+        inner = {lp.var: var}
+        body_scope = scope + [ivars, inner]
+        self.emit(d, f"for b{var} in range_({trip}):")
+        n_lines = len(self.lines)
+        self.body(lp.body, body_scope, dict(loads), d + 1)
+        for iv in lp.ivars:
+            b = ivars[iv.name]
+            cur, ca, cb, _ = self.value(b)
+            step, sa, sb, _ = self.expr(iv.step, body_scope, loads)
+            self.arith(ca, cb, sa, sb)
+            self.assign(b, ca and sa, False)
+            op = "+" if iv.op == "+" else "*"
+            self.emit(d + 1, f"{cur} = {cur} {op} {step}")
+        if len(self.lines) == n_lines:
+            self.emit(d + 1, "pass")
+        if self.hooked:
+            self.emit(d, f"loop_hook(L{k}, 'exit', {self.reader(scope)})")
+
+    def run_aux(self, op_id, scope, loads, d, strict):
+        exprs = self.aux.get(op_id)
+        if not exprs:
+            return
+        srcs = [self.expr(e, scope, loads)[0] for e in exprs]
+        if strict:
+            self.emit(d, f"aux_hook({op_id!r}, ({''.join(s + ', ' for s in srcs)}))")
+            return
+        # guard-false rows: operands the guard protected become NaN
+        for j, src in enumerate(srcs):
+            self.emit(d, "try:")
+            self.emit(d + 1, f"x{j} = {src}")
+            self.emit(d, "except Exception:")
+            self.emit(d + 1, f"x{j} = nan_")
+        names = "".join(f"x{j}, " for j in range(len(srcs)))
+        self.emit(d, f"aux_hook({op_id!r}, ({names}))")
+
+    def body(self, stmts, scope, loads, d):
+        inner = scope[-1]
+        for s in stmts:
+            if isinstance(s, Load):
+                addr = self.as_int(*self.expr(s.addr, scope, loads)[::3])
+                b = self.bind()
+                self.emit(d, f"a_ = {addr}")
+                self.emit(d, f"b{b} = {self.array(s.array)}[a_]")
+                self.run_aux(s.id, scope, loads, d, strict=True)
+                self.emit(d, f"trace_hook({s.id!r}, a_, False, True, float_(b{b}))")
+                self.assign(b, False, False)
+                loads[s.id] = b
+            elif isinstance(s, Store):
+                arr = self.array(s.array)
+                self.stored.add(s.array)
+                addr = self.as_int(*self.expr(s.addr, scope, loads)[::3])
+                self.emit(d, f"a_ = {addr}")
+                dv = d
+                if s.guard is not None:
+                    guard = self.expr(s.guard, scope, loads)[0]
+                    self.emit(d, f"g_ = {guard}")
+                    self.emit(d, "if g_:")
+                    dv = d + 1
+                self.run_aux(s.id, scope, loads, dv, strict=True)
+                val = self.expr(s.value, scope, loads)[0]
+                self.emit(dv, f"v_ = {val}")
+                self.emit(dv, f"trace_hook({s.id!r}, a_, True, True, float_(v_))")
+                self.emit(dv, f"{arr}[a_] = K(v_)")
+                if s.guard is not None:
+                    self.emit(d, "else:")
+                    self.run_aux(s.id, scope, loads, d + 1, strict=False)
+                    self.emit(d + 1, f"trace_hook({s.id!r}, a_, True, False, None)")
+            elif isinstance(s, SetLocal):
+                src, agree, boolish, _ = self.expr(s.value, scope, loads)
+                try:
+                    b = self.resolve(scope, s.name)
+                except _Uncompilable:
+                    b = inner[s.name] = self.bind()
+                self.assign(b, agree, boolish)
+                self.emit(d, f"b{b} = {src}")
+            elif isinstance(s, Loop):
+                self.loop(s, scope, loads, d)
+            else:
+                raise _Uncompilable(f"unknown stmt {s!r}")
+
+    def source(self) -> str:
+        head = [
+            "def walk(A, K, P, C, L, trace_hook, aux_hook, loop_hook):",
+            "    int_, float_, range_, min_, max_ = int, float, range, min, max",
+            "    nan_ = _nan",
+        ]
+        head += [f"    A{j} = A[{j}]  # {n}" for n, j in self.arrays.items()]
+        head += [f"    p{j} = P[{j}]  # {n}" for n, j in self.params.items()]
+        head += [f"    c{j} = C[{j}]" for j in range(len(self.consts))]
+        head += [f"    {fn} = _UN_FNS[{op!r}]" for op, fn in self.uns.items()]
+        if self.hooked:
+            head += [f"    L{j} = L[{j}]" for j in range(self.n_loop)]
+        return "\n".join(head + self.lines + ["    return None", ""])
+
+
+def _same(v):
+    return v
+
+
+# scalar types that compute alike beside a Python float or int and
+# beside the numpy float64 or int64 it stands for (numpy takes Python
+# scalars as weak: np.float32(x) * 0.5 stays float32)
+_WIDE_SCALARS = (bool, int, float, np.bool_, np.int64, np.float64)
+
+
+def compile_walk(
+    program: Program,
+    aux_exprs: Optional[dict[str, tuple]] = None,
+    with_loop_hook: bool = False,
+) -> Optional[Callable]:
+    """``interpret`` with hooks, compiled to one Python function.
+
+    Returns ``walk(program, arrays, params, trace_hook=None,
+    aux_hook=None, loop_hook=None)``, which makes the same hook calls in
+    the same order with the same values and returns the same final
+    arrays as ``interpret(program, arrays, params, trace_hook,
+    aux_exprs, aux_hook, loop_hook)``; ``loop_hook`` is called only when
+    compiled ``with_loop_hook``, and its ``reader`` answers at the time
+    of the call. ``program`` may be any program of the same
+    ``fingerprint()``: its Loop objects are the ones ``loop_hook``
+    receives. ``walk`` returns None, and calls no hook, where an array
+    or parameter the program names is missing.
+
+    Loops become ``for`` loops and every binding a Python local. Where
+    every array the program names is one-dimensional float64, or int64
+    and never stored to, and every constant and parameter it reads is a
+    Python scalar or a 64-bit numpy one, the arrays are walked as Python
+    lists: their floats and ints compute as numpy's float64 and int64
+    scalars do, and a store keeps ``float(value)`` as a float64 array
+    would. Otherwise they stay numpy arrays and every value has the type
+    ``interpret`` gives it.
+
+    Returns None where Python cannot compile the source (loops nested
+    about 20 deep) and where the program needs ``interpret``'s run-time
+    behaviour: a name or load value read where it may be undefined
+    (``interpret`` raises there, or puts NaN in a guard-false aux row),
+    arithmetic on a comparison's result that reads an array (logical
+    on numpy booleans, integral on Python's), or ``//`` and ``%`` by a
+    value read from an array (a zero divisor raises on Python numbers).
+    Not reproduced on lists: int64 arithmetic that overflows (numpy
+    wraps) and int64 values beyond 2**53 compared with floats.
+    """
+    aux_exprs = aux_exprs or {}
+    disagree: set[int] = set()
+    boolish: set[int] = set()
+    try:
+        while True:
+            gen = _WalkSource(
+                program, aux_exprs, with_loop_hook, disagree, boolish
+            )
+            if not gen.grown:
+                break
+        # Python refuses more than 20 nested blocks
+        code = compile(gen.source(), f"<walk of {program.name}>", "exec")
+    except (_Uncompilable, SyntaxError):
+        return None
+    ns = {"_nan": np.nan, "_UN_FNS": _UN_FNS}
+    exec(code, ns)
+    fn = ns["walk"]
+    consts = tuple(gen.consts)
+    array_names = tuple(gen.arrays)
+    param_names = tuple(gen.params)
+    stored = [j for j, n in enumerate(array_names) if n in gen.stored]
+    wide_consts = all(type(c) in _WIDE_SCALARS for c in consts)
+
+    def listable(j, a):
+        return a.ndim == 1 and (a.dtype == np.float64 or (
+            a.dtype == np.int64 and j not in stored
+        ))
+
+    def walk(program, arrays, params, trace_hook=None, aux_hook=None,
+             loop_hook=None):
+        if loop_hook is not None and not with_loop_hook:
+            raise ValueError("walk compiled without with_loop_hook")
+        params = params or {}
+        if any(n not in arrays for n in array_names) or any(
+            p not in params for p in param_names
+        ):
+            return None
+        out = {k: np.array(v, copy=True) for k, v in arrays.items()}
+        data = [out[n] for n in array_names]
+        pvals = [params[p] for p in param_names]
+        lists = wide_consts and all(
+            listable(j, a) for j, a in enumerate(data)
+        ) and all(type(v) in _WIDE_SCALARS for v in pvals)
+        if lists:
+            data = [a.tolist() for a in data]
+        fn(
+            data, float if lists else _same, pvals, consts,
+            _loops_in_order(program) if with_loop_hook else (),
+            trace_hook or (lambda *_: None), aux_hook, loop_hook,
+        )
+        if lists:
+            for j in stored:
+                out[array_names[j]][:] = data[j]
+        return out
+
+    return walk

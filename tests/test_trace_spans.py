@@ -199,3 +199,24 @@ def test_a_raising_span_still_closes_its_tally():
     with trace.span("next"):
         pass
     assert set(trace.RECENT[-1]) == {"repro.next"}
+
+
+def test_walk_span_counts_the_generated_walk(monkeypatch):
+    """``compiled``: the generated walk ran; ``walk_compiles``: this call
+    generated it. The second call on a program reuses the first's; a
+    speculative program keeps the interpreter."""
+    monkeypatch.setattr(executor, "_WALKS", {})
+    prog, arrays, params = _spmv()
+    seen = []
+    for _ in range(2):
+        executor.execute(prog, arrays, params)
+        seen.append(trace.RECENT[-1]["repro.plan.walk"][2])
+    assert [(s["compiled"], s["walk_compiles"]) for s in seen] == [
+        (1, 1), (1, 0)]
+    assert seen[0]["requests"] == seen[1]["requests"] > 0
+
+    spec = programs.get(programs.SPEC_KERNELS[0])
+    prog, arrays, params = spec.make(spec.default_scale)
+    executor.execute(prog, arrays, params, speculation="auto")
+    stats = trace.RECENT[-1]["repro.plan.walk"][2]
+    assert (stats["compiled"], stats["walk_compiles"]) == (0, 0)

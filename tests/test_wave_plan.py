@@ -269,3 +269,51 @@ def test_floor_kernel_backends_differential(name):
                     err_msg=f"{name}@{scale}: {label} backend diverged "
                     f"from oracle ({k})",
                 )
+
+
+# ---------------------------------------------------------------------------
+# the generated oracle walk plans exactly as the interpreter does
+# ---------------------------------------------------------------------------
+
+PLAN_ARRAYS = ("req_op", "req_addr", "req_flat", "req_store", "req_valid",
+               "req_value", "req_wave", "req_step", "req_ordinal")
+
+
+def _same_array(a, b):
+    a, b = np.asarray(a), np.asarray(b)
+    return a.dtype == b.dtype and a.shape == b.shape and (
+        a.tobytes() == b.tobytes()
+    )
+
+
+@pytest.mark.parametrize("name", programs.TABLE1)
+def test_generated_walk_plans_bit_identically(name, monkeypatch):
+    from repro import trace
+
+    bench = programs.get(name)
+    spec = "auto" if bench.speculative else "off"
+    prog, arrays, params = bench.make(max(8, bench.default_scale // 64))
+    compiled = _build((prog, arrays, params), speculation=spec)
+    assert trace.RECENT[-1]["repro.plan.walk"][2]["compiled"] == 1
+    monkeypatch.setattr(executor, "_compiled_walk",
+                        lambda *args: (None, False))
+    interp = _build((prog, arrays, params), speculation=spec)
+    assert trace.RECENT[-1]["repro.plan.walk"][2]["compiled"] == 0
+
+    for f in PLAN_ARRAYS:
+        assert _same_array(getattr(compiled, f), getattr(interp, f)), f
+    assert compiled.op_ids == interp.op_ids
+    assert compiled.env.keys() == interp.env.keys()
+    for op_id, slots in interp.env.items():
+        assert len(compiled.env[op_id]) == len(slots)
+        for a, b in zip(compiled.env[op_id], slots):
+            assert _same_array(a, b), op_id
+    assert compiled.dep_maps.keys() == interp.dep_maps.keys()
+    for op_id, per_ld in interp.dep_maps.items():
+        assert compiled.dep_maps[op_id].keys() == per_ld.keys()
+        for ld, rows in per_ld.items():
+            assert _same_array(compiled.dep_maps[op_id][ld], rows), op_id
+    assert len(compiled.hint_checks) == len(interp.hint_checks)
+    for a, b in zip(compiled.hint_checks, interp.hint_checks):
+        assert a["op"] == b["op"] and a["innermost"] == b["innermost"]
+        assert _same_array(a["resets"], b["resets"])

@@ -50,6 +50,7 @@ PUBLIC_API = [
     ("repro.core.schedule", "trace_program"),
     ("repro.core.monotonic", "analyze_program"),
     ("repro.core.loopir", "interpret"),
+    ("repro.core.loopir", "compile_walk"),
     ("repro.core.loopir", "Program"),
     ("repro.core.dae", "decouple"),
     ("repro.core.dae", "record_cu_script"),
