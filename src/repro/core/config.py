@@ -82,8 +82,9 @@ class RunConfig:
       * ``trace_mode`` — AGU/CU front-end (simulate, executor, DSE;
         proven bit-identical across values, so excluded from the DSE
         result identity).
-      * ``speculation`` — loss-of-decoupling policy (simulate,
-        executor, DSE).
+      * ``speculation`` — loss-of-decoupling policy (simulate, DSE;
+        the executor admits loss-of-decoupling programs whatever it
+        says, since its results do not depend on it).
       * ``predictor`` — speculative-AGU value predictor (simulate,
         executor, DSE; dead unless the point speculates).
       * ``spec_runahead`` / ``fifo_depth`` / ``fifo_latency`` —

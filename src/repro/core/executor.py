@@ -224,6 +224,7 @@ def _trace_stream(
     traces = schedlib.trace_program(
         program, dae, arrays, params, mode=trace_mode,
         oracle_loads=oracle_loads, predictor=predictor,
+        spec_span=lambda: trace.span("plan.spec"),
     )
     loop_pos, op_pos = program.static_positions()
     op_path = {op.id: path for op, path in program.mem_ops()}
@@ -279,13 +280,16 @@ def build_wave_plan(
     ``trace_mode != "interp"`` additionally builds op/addr/kind streams
     through the trace compiler and asserts they agree with the walk.
 
-    ``speculation="auto"`` admits loss-of-decoupling programs
-    (load-dependent trips/addresses, DESIGN.md §10): the wave partition
-    works off the *true* post-squash request stream — phantom squash
-    traffic is a DU-timing artifact and has no wave-executor analogue.
-    ``predictor`` (``dae.PREDICTORS``) is accepted for API uniformity
-    with ``simulate()``: the post-squash streams are identical under
-    every predictor, so the emitted plan does not depend on it.
+    Loss-of-decoupling programs (load-dependent trips/addresses,
+    DESIGN.md §10) are admitted with no option set: the decoupling pass
+    marks their PEs speculative, as ``simulate(speculation="auto")``
+    does, and the wave partition works off the *true* post-squash
+    request stream — phantom squash traffic is a DU-timing artifact and
+    has no wave-executor analogue. A decoupled program gets no
+    speculative PE. ``speculation`` and ``predictor``
+    (``dae.PREDICTORS``) are accepted for API uniformity with
+    ``simulate()``: the post-squash streams are identical under every
+    value of either, so the plan depends on neither.
 
     ``batch_waves`` (default on) coarsens the wave partition into
     batched steps (WavePlan contract 5); ``False`` keeps one step per
@@ -307,9 +311,9 @@ def build_wave_plan(
     pop ``k``) — ``validate_plan`` asserts both per edge.
 
     ``config=`` accepts a ``repro.core.config.RunConfig``; the
-    executor consumes its ``trace_mode``/``speculation``/``predictor``/
-    ``batch_waves``/``fifo_depth``/``symbolic_admission`` fields and
-    ignores the simulator-only ones (``mode``, ``engine``, ...). A
+    executor consumes its ``trace_mode``/``predictor``/``batch_waves``/
+    ``fifo_depth``/``symbolic_admission`` fields and ignores the
+    simulator-only ones (``mode``, ``engine``, ``speculation``, ...). A
     conflicting explicit kwarg raises ``config.ConfigConflict``.
     """
     cfg = cfglib.resolve(
@@ -317,9 +321,7 @@ def build_wave_plan(
         predictor=predictor, batch_waves=batch_waves,
         symbolic_admission=symbolic_admission,
     )
-    trace_mode, speculation, predictor = (
-        cfg.trace_mode, cfg.speculation, cfg.predictor
-    )
+    trace_mode, predictor = cfg.trace_mode, cfg.predictor
     batch_waves, symbolic_admission = cfg.batch_waves, cfg.symbolic_admission
     # fifo_depth=None in a config means "default" (4 here, matching
     # SimParams.fifo_depth) — only a real config value can conflict
@@ -334,8 +336,8 @@ def build_wave_plan(
     params = params or {}
     with trace.span("plan"):
         return _plan(
-            program, arrays, params, trace_mode, speculation, predictor,
-            batch_waves, fifo_depth, symbolic_admission,
+            program, arrays, params, trace_mode, predictor, batch_waves,
+            fifo_depth, symbolic_admission,
         )
 
 
@@ -365,7 +367,6 @@ def _plan(
     arrays: dict[str, np.ndarray],
     params: dict[str, int],
     trace_mode: str,
-    speculation: str,
     predictor: str,
     batch_waves: bool,
     fifo_depth: int,
@@ -377,8 +378,10 @@ def _plan(
     from repro.core import fifo as fifolib
 
     with trace.span("plan.analyze"):
+        # the result does not depend on speculation (see
+        # build_wave_plan): admit what the decoupling pass can mark
         dae = daelib.decouple(
-            program, speculation=speculation, predictor=predictor
+            program, speculation="auto", predictor=predictor
         )
         fifo_spec = None
         if dae.fifo_edges:
@@ -1137,13 +1140,13 @@ def execute(
     bit-identical to ``loopir.interpret`` for every Table-1 kernel in
     both trace modes (tests/test_pallas_parity.py).
 
-    ``speculation="auto"`` admits loss-of-decoupling programs
-    (load-dependent trips/addresses, DESIGN.md §10): the wave partition
-    works off the *true* post-squash request stream — phantom squash
-    traffic is a DU-timing artifact and has no wave-executor analogue.
-    ``predictor`` (``dae.PREDICTORS``) is accepted for API uniformity:
-    final arrays and the wave partition are identical under every
-    predictor (tests/test_speculation.py pins this).
+    Loss-of-decoupling programs (load-dependent trips/addresses,
+    DESIGN.md §10) run with no option set, through the speculative AGU
+    trace (``build_wave_plan``); decoupled programs get no speculative
+    PE. ``speculation`` and ``predictor`` (``dae.PREDICTORS``)
+    are accepted for API uniformity with ``simulate()``: final arrays
+    and the wave partition are identical under every value of either
+    (tests/test_speculation.py pins this).
 
     ``batch_waves`` (default on) lets both backends execute batched
     conflict-free wave runs as single steps (WavePlan contract 5);
@@ -1162,9 +1165,10 @@ def execute(
 
     ``config=`` accepts a ``repro.core.config.RunConfig``; the
     executor consumes every field except the simulator-only ``mode``/
-    ``engine``/``spec_runahead``/``fifo_latency``/``static_prune``. A
-    conflicting explicit kwarg raises ``config.ConfigConflict``. Final
-    arrays are bit-identical between the two spellings.
+    ``engine``/``speculation``/``spec_runahead``/``fifo_latency``/
+    ``static_prune``. A conflicting explicit kwarg raises
+    ``config.ConfigConflict``. Final arrays are bit-identical between
+    the two spellings.
     """
     cfg = cfglib.resolve(
         config, trace_mode=trace_mode, speculation=speculation,

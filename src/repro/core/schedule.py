@@ -36,8 +36,9 @@ the offending op when a PE is outside the compiled subset.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
-from typing import Optional
+from typing import Callable, Optional
 
 import numpy as np
 
@@ -149,6 +150,7 @@ def trace_program(
     oracle_loads: Optional[dict] = None,
     predictor: str = "auto",
     spec_runahead: Optional[int] = None,
+    spec_span: Optional[Callable] = None,
 ) -> dict[str, OpTrace]:
     """Generate the AGU request streams of every memory op in every PE.
 
@@ -171,7 +173,10 @@ def trace_program(
     ``predictor`` (``dae.PREDICTORS``) and ``spec_runahead``
     (``SimParams.spec_runahead``; ``None`` = the speculate default)
     parameterize the built ``SpecPlan`` — they move gates and phantom
-    traffic only, never the request streams.
+    traffic only, never the request streams. ``spec_span()``, where
+    given, opens a span (``repro.trace.span``) around each speculative
+    PE's trace, which the trace gives the stats ``gates`` (gates it
+    opened) and ``requests`` (requests it generated).
     """
     assert mode in TRACE_MODES, f"unknown trace mode {mode!r}"
     params = params or {}
@@ -205,9 +210,15 @@ def trace_program(
                     oracle_loads = speculate.oracle_load_streams(
                         program, arrays, params
                     )
-            t = speculate.trace_spec_pe(
-                pe, dae.spec[pe.id], arrays, params, oracle_loads, spec_plan
-            )
+            with (spec_span or contextlib.nullcontext)() as span:
+                gates = spec_plan.n_gates
+                t = speculate.trace_spec_pe(
+                    pe, dae.spec[pe.id], arrays, params, oracle_loads,
+                    spec_plan,
+                )
+                if span is not None:
+                    span.set(gates=spec_plan.n_gates - gates,
+                             requests=sum(o.n_req for o in t.ops.values()))
             if report is not None:
                 report[pe.id] = {
                     "path": "speculative",

@@ -424,6 +424,28 @@ def oracle_load_streams(
     return loads
 
 
+def _eval_in_bounds(e: ir.Expr, scope, arrays, params, loadvals):
+    """``loopir._eval`` for values the AGU only predicted: a ``Read``
+    whose index lies outside its array raises ``IndexError``, a negative
+    index included, where the oracle's evaluation would wrap or fault."""
+    if isinstance(e, ir.Read):
+        idx = int(_eval_in_bounds(e.index, scope, arrays, params, loadvals))
+        arr = arrays[e.array]
+        if not 0 <= idx < len(arr):
+            raise IndexError(f"{e.array}[{idx}] outside {len(arr)} entries")
+        return arr[idx]
+    if isinstance(e, ir.Bin):
+        return ir._binop(
+            e.op,
+            _eval_in_bounds(e.a, scope, arrays, params, loadvals),
+            _eval_in_bounds(e.b, scope, arrays, params, loadvals),
+        )
+    if isinstance(e, ir.Un):
+        return ir._UN_FNS[e.op](
+            _eval_in_bounds(e.a, scope, arrays, params, loadvals))
+    return ir._eval(e, scope, arrays, params, loadvals)
+
+
 def trace_spec_pe(
     pe: daelib.PE,
     info: daelib.SpecInfo,
@@ -550,7 +572,13 @@ def trace_spec_pe(
                 lv = dict(loadvals)
                 for l in specced:
                     lv[l] = pred_val[l]
-                trip_pred = max(0, int(eval_expr(loop.trip, scope, lv)))
+                try:
+                    trip_pred = max(0, int(_eval_in_bounds(
+                        loop.trip, scope, arrays, params, lv)))
+                except IndexError:
+                    # a predicted index outside its array: the bound
+                    # cannot be formed, so it is gated, no phantom tail
+                    trip_pred = 0
                 extra = max(0, trip_pred - max(0, trip))
                 for s in by_depth.get(d, ()):
                     if isinstance(s, (ir.Load, ir.Store)):

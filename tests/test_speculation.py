@@ -265,16 +265,20 @@ def test_executor_runs_spec_kernels(name):
     from repro.core import executor
 
     prog, arrays, params = programs.get(name).make(SCALES[name])
-    with pytest.raises(daelib.LossOfDecoupling):
-        executor.execute(prog, arrays, params)
     ra = executor.execute(prog, arrays, params, speculation="auto")
     rb = executor.execute(
         prog, arrays, params, speculation="auto", trace_mode="interp"
     )
+    # the executor admits loss-of-decoupling programs with no option
+    # set: ``speculation`` is a simulate()-only field
+    rd = executor.execute(prog, arrays, params)
     oracle = ir.interpret(prog, arrays, params)
     for k in oracle:
         np.testing.assert_array_equal(ra.arrays[k], oracle[k])
+        np.testing.assert_array_equal(rd.arrays[k], ra.arrays[k])
     np.testing.assert_array_equal(ra.waves, rb.waves)
+    np.testing.assert_array_equal(rd.waves, ra.waves)
+    np.testing.assert_array_equal(rd.plan.req_step, ra.plan.req_step)
 
 
 # ---------------------------------------------------------------------------
@@ -329,6 +333,47 @@ def test_cross_pe_load_dependence_always_rejects():
     for mode in ("off", "auto"):
         with pytest.raises(daelib.LossOfDecoupling, match="cross-PE"):
             daelib.decouple(prog, speculation=mode)
+
+
+@pytest.mark.parametrize("engine", ("cycle", "event"))
+def test_mispredicted_bound_outside_its_array_does_not_fault(engine):
+    """A row walk whose stride-predicted row id runs past the end of
+    ``rp``: the phantom-trip estimate would read ``rp`` outside it. The
+    bound is counted as gated, with no phantom tail; simulate() and the
+    wave executor run the program oracle-exact."""
+    from repro.core import executor
+
+    n = 8
+    u = ir.LoadVal("ld_u")
+    x_at = ir.Read("rp", u) + ir.Var("e")
+    prog = ir.Program("rows", loops=(
+        ir.Loop("i", ir.Param("m", 0, 16), (
+            ir.Load("ld_u", "idx", ir.Var("i")),
+            ir.Loop("e", ir.Read("rp", u + 1) - ir.Read("rp", u), (
+                ir.Load("ld_x", "x", x_at),
+                ir.Store("st_x", "x", x_at, ir.LoadVal("ld_x") + 1.0),
+            )),
+        )),
+    ), params=("m",))
+    # rows 0..7 in order, then row 0: the stride predictor says row 8,
+    # and rp[9] does not exist
+    seq = [*range(n), 0]
+    arrays = {
+        "idx": np.array(seq, dtype=np.float64),
+        "rp": np.arange(0, 2 * n + 1, 2, dtype=np.int64),
+        "x": np.zeros(2 * n),
+    }
+    params = {"m": len(seq)}
+    oracle = ir.interpret(prog, arrays, params)
+    res = simulator.simulate(
+        prog, arrays, params, mode="FUS2", engine=engine,
+        speculation="auto", predictor="stride", validate=True,
+    )
+    assert res.spec_stats["mispredictions"] >= 1
+    ex = executor.execute(prog, arrays, params, predictor="stride")
+    for k in oracle:
+        np.testing.assert_array_equal(res.arrays[k], oracle[k])
+        np.testing.assert_array_equal(ex.arrays[k], oracle[k])
 
 
 def test_self_bounding_trip_rejects_even_under_auto():

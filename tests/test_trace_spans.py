@@ -220,3 +220,31 @@ def test_walk_span_counts_the_generated_walk(monkeypatch):
     executor.execute(prog, arrays, params, speculation="auto")
     stats = trace.RECENT[-1]["repro.plan.walk"][2]
     assert (stats["compiled"], stats["walk_compiles"]) == (0, 0)
+
+
+def test_spec_span_counts_the_speculative_trace():
+    """``repro.plan.spec`` sits inside ``repro.plan.trace`` once per
+    speculative PE, with the gates its trace opened and the requests it
+    generated; a decoupled program opens none."""
+    from repro.core import dae as daelib
+    from repro.core import schedule
+
+    spec = programs.get("bfs_front")
+    prog, arrays, params = spec.make(spec.default_scale)
+    res = executor.execute(prog, arrays, params)
+    tally = trace.RECENT[-1]
+    dae = daelib.decouple(prog, speculation="auto")
+    plans = []
+    traces = schedule.trace_program(prog, dae, arrays, params,
+                                    spec_out=plans)
+    spec_ops = [o for pe in dae.spec for o in dae.pes[pe].mem_ops]
+    assert tally["repro.plan.spec"][1] == len(dae.spec) >= 1
+    assert tally["repro.plan.spec"][2] == {
+        "gates": plans[0].n_gates,
+        "requests": sum(traces[o].n_req for o in spec_ops),
+    }
+    assert 0 < tally["repro.plan.spec"][2]["requests"] < res.plan.n_requests
+    assert tally["repro.plan.spec"][0] <= tally["repro.plan.trace"][0]
+
+    executor.execute(*_spmv())
+    assert "repro.plan.spec" not in trace.RECENT[-1]
