@@ -53,7 +53,8 @@ with the Pallas kernels and the MoE dispatch path.
 request stream's op ids / addresses / kinds come from: the AGU trace
 compiler (``schedule.trace_program``) plus one lexsort of polyhedral
 2d+1 keys, with the oracle walk supplying the reference value/valid
-stream; ``"interp"`` keeps the original pure-hook path. The oracle walk
+stream; ``"interp"`` keeps the original pure-hook path, which ``"auto"``
+also takes for a program with a speculative PE. The oracle walk
 runs in full either way — backends *compute* store values through the
 op tables, and the walk's values are the per-request reference that
 pins any divergence to the first offending request.
@@ -207,24 +208,20 @@ def _trace_stream(
     arrays: dict[str, np.ndarray],
     params: dict[str, int],
     trace_mode: str,
-    oracle_loads=None,
-    predictor: str = "auto",
 ) -> tuple[list[str], list[int], list[bool]]:
     """Program-order (op id, address, is_store) stream from AGU traces.
 
     Global program order is lexicographic on the polyhedral 2d+1 key —
     static body positions and the §4 never-reset counters interleaved,
     with the op's own body position last. Supplies everything except
-    values/valid bits, which only the oracle walk can produce
-    (``oracle_loads`` feeds the speculative AGU of loss-of-decoupling
-    PEs from that same walk).
+    values/valid bits, which only the oracle walk can produce.
+    ``build_wave_plan`` calls it for decoupled programs only: a
+    speculative PE's post-squash stream is the walk's own (see there).
     """
     from repro.core import schedule as schedlib
 
     traces = schedlib.trace_program(
         program, dae, arrays, params, mode=trace_mode,
-        oracle_loads=oracle_loads, predictor=predictor,
-        spec_span=lambda: trace.span("plan.spec"),
     )
     loop_pos, op_pos = program.static_positions()
     op_path = {op.id: path for op, path in program.mem_ops()}
@@ -275,9 +272,8 @@ def build_wave_plan(
     One hooked oracle walk supplies (a) the reference value/valid
     streams, (b) the op-table environment slots via the ``aux_exprs``
     interpreter hook, (c) the dep alignment maps (most recent request
-    of each feeding load at every store request), and — for speculative
-    programs — (d) the load streams the run-ahead AGU predicts against.
-    ``trace_mode != "interp"`` additionally builds op/addr/kind streams
+    of each feeding load at every store request). For a decoupled
+    program ``trace_mode != "interp"`` builds the op/addr/kind streams
     through the trace compiler and asserts they agree with the walk.
 
     Loss-of-decoupling programs (load-dependent trips/addresses,
@@ -285,7 +281,12 @@ def build_wave_plan(
     marks their PEs speculative, as ``simulate(speculation="auto")``
     does, and the wave partition works off the *true* post-squash
     request stream — phantom squash traffic is a DU-timing artifact and
-    has no wave-executor analogue. A decoupled program gets no
+    has no wave-executor analogue. That stream is the walk's own, in
+    program order, so under ``"auto"`` the walk's hook supplies it and
+    no speculative AGU trace runs (``"compiled"`` still raises
+    ``TraceCompileError``; gates and phantoms stay ``simulate()``'s).
+    The ``repro.plan.trace`` span's ``from_walk`` stat is 1 where the
+    walk supplied the stream. A decoupled program gets no
     speculative PE. ``speculation`` and ``predictor``
     (``dae.PREDICTORS``) are accepted for API uniformity with
     ``simulate()``: the post-squash streams are identical under every
@@ -422,13 +423,20 @@ def _plan(
 
     # --- pass 1: hooked oracle walk (reference + CU operand capture) -----
     per_op_vv: dict[str, list[tuple[bool, Optional[float]]]] = {}
-    load_streams: dict[str, list[float]] = {}
     env_rows: dict[str, list[tuple]] = {op_id: [] for op_id in aux_exprs}
     dep_rows: dict[str, dict[str, list[int]]] = {
         op_id: {ld: [] for ld in t.deps} for op_id, t in tables.items()
     }
     counts: dict[str, int] = {}
-    interp_stream: list[tuple[str, int, bool]] = []
+    # where the walk supplies the program-order op/addr/kind stream: under
+    # "interp", and for a speculative program under "auto" (its AGU trace
+    # would rebuild the same post-squash stream)
+    from_walk = trace_mode == "interp" or (
+        bool(dae.spec) and trace_mode == "auto"
+    )
+    req_op_l: list[str] = []
+    req_addr_l: list[int] = []
+    req_store_l: list[bool] = []
     # FIFO token capture: (pos in the real request stream, kind, edge
     # idx, token value) — pops fire at consumer leaf-instance entry
     # (before the instance's own requests), pushes at producer instance
@@ -469,11 +477,10 @@ def _plan(
                 rows.append(counts.get(ld, 0) - 1)
         else:
             counts[op_id] = counts.get(op_id, 0) + 1
-            if dae.spec:
-                # only the speculative AGU consumes the load streams
-                load_streams.setdefault(op_id, []).append(value)
-        if trace_mode == "interp":
-            interp_stream.append((op_id, addr, is_store))
+        if from_walk:
+            req_op_l.append(op_id)
+            req_addr_l.append(addr)
+            req_store_l.append(is_store)
 
     fifo_loop_hook = None
     if fifo_spec:
@@ -540,21 +547,18 @@ def _plan(
                  walk_compiles=int(compiles))
 
     if trace_mode != "interp":
-        with trace.span("plan.trace"):
-            req_op_l, req_addr_l, req_store_l = _trace_stream(
-                program, dae, arrays, params, trace_mode,
-                oracle_loads=load_streams if dae.spec else None,
-                predictor=predictor,
-            )
-            n_oracle = sum(len(v) for v in per_op_vv.values())
-            assert n_oracle == len(req_op_l), (
-                f"trace stream has {len(req_op_l)} requests, oracle walk "
-                f"{n_oracle} — trace compiler divergence"
-            )
-    else:
-        req_op_l = [r[0] for r in interp_stream]
-        req_addr_l = [r[1] for r in interp_stream]
-        req_store_l = [r[2] for r in interp_stream]
+        with trace.span("plan.trace", from_walk=int(from_walk)):
+            if not from_walk:
+                # a speculative PE under "compiled" raises
+                # TraceCompileError here
+                req_op_l, req_addr_l, req_store_l = _trace_stream(
+                    program, dae, arrays, params, trace_mode,
+                )
+                n_oracle = sum(len(v) for v in per_op_vv.values())
+                assert n_oracle == len(req_op_l), (
+                    f"trace stream has {len(req_op_l)} requests, oracle "
+                    f"walk {n_oracle} — trace compiler divergence"
+                )
 
     with trace.span("plan.streams"):
         op_ids = [op.id for op, _ in program.mem_ops()]

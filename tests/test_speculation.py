@@ -41,12 +41,15 @@ Pins the loss-of-decoupling speculation subsystem end to end:
     grow, the paper's evaluation set may not).
 """
 
+import collections
+
 import numpy as np
 import pytest
 
 import loopir_strategies as strat
 from repro.core import dae as daelib
 from repro.core import engine_event
+from repro.core import executor
 from repro.core import loopir as ir
 from repro.core import programs
 from repro.core import schedule as schedlib
@@ -279,6 +282,80 @@ def test_executor_runs_spec_kernels(name):
     np.testing.assert_array_equal(ra.waves, rb.waves)
     np.testing.assert_array_equal(rd.waves, ra.waves)
     np.testing.assert_array_equal(rd.plan.req_step, ra.plan.req_step)
+
+
+def _bfs_rmat(scale, seed):
+    """An instance of the ``bfs_rmat`` benchmark configuration (Graph500
+    kernel 2, bench/configs) at ``scale``."""
+    import importlib.util
+    import json
+    import pathlib
+
+    configs = pathlib.Path(__file__).resolve().parents[1] / "bench" / "configs"
+    mods = {}
+    for name in ("bfs_rmat", "bfs_rmat_ref"):
+        spec = importlib.util.spec_from_file_location(
+            f"_cfg_{name}", configs / f"{name}.py"
+        )
+        mods[name] = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mods[name])
+    params = {
+        **json.loads((configs / "bfs_rmat.json").read_text())["params"],
+        "scale": scale,
+    }
+    arrays, pp = mods["bfs_rmat_ref"].generate(
+        params, np.random.default_rng(seed), np.random.default_rng(seed + 1)
+    )
+    return mods["bfs_rmat"].build(params), arrays, pp
+
+
+_GENERATORS = {
+    "spec": (strat.random_spec_program, 2000),
+    "stride": (strat.random_stride_spec_program, 3000),
+    "context": (strat.random_context_spec_program, 4000),
+}
+_STREAM_CASES = (
+    [(f"kernel-{n}", lambda n=n: programs.get(n).make(SCALES[n]))
+     for n in programs.SPEC_KERNELS]
+    + [(f"bfs_rmat-{sc}-{sd}", lambda sc=sc, sd=sd: _bfs_rmat(sc, sd))
+       for sc, sd in ((5, 0), (6, 1), (6, 2))]
+    + [(f"{g}-{seed}",
+        lambda g=g, seed=seed: _GENERATORS[g][0](
+            np.random.default_rng(_GENERATORS[g][1] + seed)))
+       for g in _GENERATORS for seed in range(4)]
+)
+
+
+@pytest.mark.parametrize(
+    "make", [m for _, m in _STREAM_CASES], ids=[i for i, _ in _STREAM_CASES]
+)
+def test_spec_plan_stream_is_the_agu_trace(make):
+    """A speculative program's plan takes its op/addr/kind stream from
+    the oracle walk: that stream is the speculative AGU trace's, request
+    for request. Every other plan array is a function of this stream and
+    the walk's captures, and the executed arrays are the oracle's."""
+    prog, arrays, params = make()
+    dae = daelib.decouple(prog, speculation="auto")
+    assert dae.spec
+    res = executor.execute(prog, arrays, params)
+    plan = res.plan
+    op_l, addr_l, store_l = executor._trace_stream(
+        prog, dae, arrays, params, "auto"
+    )
+    index = {o: i for i, o in enumerate(plan.op_ids)}
+    assert plan.n_requests == len(op_l) > 0
+    np.testing.assert_array_equal(plan.req_op, [index[o] for o in op_l])
+    np.testing.assert_array_equal(plan.req_addr, addr_l)
+    np.testing.assert_array_equal(plan.req_store, store_l)
+    np.testing.assert_array_equal(
+        plan.req_flat, [plan.base[plan.op_array[o]] + a
+                        for o, a in zip(op_l, addr_l)]
+    )
+    assert {o: n for o, n in plan.op_nreq.items() if n} == \
+        collections.Counter(op_l)
+    oracle = ir.interpret(prog, arrays, params)
+    for k in oracle:
+        np.testing.assert_array_equal(res.arrays[k], oracle[k], err_msg=k)
 
 
 # ---------------------------------------------------------------------------

@@ -223,20 +223,26 @@ def test_walk_span_counts_the_generated_walk(monkeypatch):
 
 
 def test_spec_span_counts_the_speculative_trace():
-    """``repro.plan.spec`` sits inside ``repro.plan.trace`` once per
-    speculative PE, with the gates its trace opened and the requests it
-    generated; a decoupled program opens none."""
+    """``execute()`` on a speculative program opens no ``repro.plan.spec``:
+    its stream comes from the walk. ``schedule.trace_program`` still
+    opens the span once per speculative PE, with the gates its trace
+    opened and the requests it generated."""
     from repro.core import dae as daelib
     from repro.core import schedule
 
     spec = programs.get("bfs_front")
     prog, arrays, params = spec.make(spec.default_scale)
     res = executor.execute(prog, arrays, params)
-    tally = trace.RECENT[-1]
+    assert "repro.plan.spec" not in trace.RECENT[-1]
+
     dae = daelib.decouple(prog, speculation="auto")
     plans = []
-    traces = schedule.trace_program(prog, dae, arrays, params,
-                                    spec_out=plans)
+    with trace.span("outer"):
+        traces = schedule.trace_program(
+            prog, dae, arrays, params, spec_out=plans,
+            spec_span=lambda: trace.span("plan.spec"),
+        )
+    tally = trace.RECENT[-1]
     spec_ops = [o for pe in dae.spec for o in dae.pes[pe].mem_ops]
     assert tally["repro.plan.spec"][1] == len(dae.spec) >= 1
     assert tally["repro.plan.spec"][2] == {
@@ -244,7 +250,19 @@ def test_spec_span_counts_the_speculative_trace():
         "requests": sum(traces[o].n_req for o in spec_ops),
     }
     assert 0 < tally["repro.plan.spec"][2]["requests"] < res.plan.n_requests
-    assert tally["repro.plan.spec"][0] <= tally["repro.plan.trace"][0]
+    assert tally["repro.plan.spec"][0] <= tally["repro.outer"][0]
 
-    executor.execute(*_spmv())
-    assert "repro.plan.spec" not in trace.RECENT[-1]
+
+@pytest.mark.parametrize("name, from_walk", [("bfs_front", 1), ("spmv", 0)])
+def test_trace_span_says_where_the_stream_came_from(name, from_walk):
+    """``from_walk`` on ``repro.plan.trace``: 1 where the walk supplied
+    a speculative program's stream, 0 where AGU traces built it."""
+    if name == "spmv":
+        prog, arrays, params = _spmv()
+    else:
+        spec = programs.get(name)
+        prog, arrays, params = spec.make(spec.default_scale)
+    executor.execute(prog, arrays, params)
+    stats = trace.RECENT[-1]["repro.plan.trace"]
+    assert stats[1] == 1
+    assert stats[2] == {"from_walk": from_walk}
