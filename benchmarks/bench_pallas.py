@@ -28,9 +28,8 @@ Produces the evidence file committed as ``BENCH_PALLAS.json``:
     WavePlan.
 
 ``--smoke`` is the tier-1 CI gate: all nine Table-1 kernels (and the
-speculative three) at reduced scales through the real wave-backend
-path on the default JAX device, both trace modes, oracle-asserted, no
-JSON.
+speculative ones) at reduced scales through the real wave-backend
+path on the default JAX device, oracle-asserted, no JSON.
 
 Usage:
     PYTHONPATH=src:. python benchmarks/bench_pallas.py \
@@ -51,8 +50,8 @@ from repro.core import executor, loopir as ir, programs, simulator
 from repro.kernels import wave_exec
 from benchmarks.paper_table1 import SCALES, scaled
 
-# tier-1 smoke scales: small enough that 12 kernels x 2 trace modes
-# fit the tier-1 wall-clock budget on XLA's CPU backend
+# tier-1 smoke scales: small enough that every kernel fits the tier-1
+# wall-clock budget on XLA's CPU backend
 SMOKE_SCALES = {
     "RAWloop": 256, "WARloop": 256, "WAWloop": 256,
     "bnn": 16, "pagerank": 24, "fft": 64, "matpower": 16,
@@ -147,8 +146,7 @@ def frontier_crosschecks(name, plan, arrays, interpret=True):
     return done
 
 
-def run_kernel(name, scale, *, trace_mode="auto", check=True,
-               seq_steps=0):
+def run_kernel(name, scale, *, check=True, seq_steps=0):
     """One kernel through the Pallas wave backend; returns (row, plan)."""
     bench = programs.get(name)
     prog, arrays, params = bench.make(scale)
@@ -156,9 +154,7 @@ def run_kernel(name, scale, *, trace_mode="auto", check=True,
     oracle = ir.interpret(prog, arrays, params)
 
     t0 = time.time()
-    plan = executor.build_wave_plan(
-        prog, arrays, params, trace_mode=trace_mode, speculation=spec,
-    )
+    plan = executor.build_wave_plan(prog, arrays, params, speculation=spec)
     t_plan = time.time() - t0
 
     t0 = time.time()
@@ -181,7 +177,6 @@ def run_kernel(name, scale, *, trace_mode="auto", check=True,
     row = {
         "scale": scale,
         "speculative": bench.speculative,
-        "trace_mode": trace_mode,
         "n_requests": plan.stats.n_requests,
         "n_waves": plan.stats.n_waves,
         "n_steps": plan.stats.n_steps,
@@ -222,23 +217,15 @@ def run_kernel(name, scale, *, trace_mode="auto", check=True,
 
 def smoke():
     """Tier-1 CI smoke: every Table-1 + speculative kernel through the
-    Pallas wave backend at SMOKE_SCALES, oracle-asserted; Table-1 also
-    runs the compiled trace mode and pins identical waves."""
+    Pallas wave backend at SMOKE_SCALES, oracle-asserted."""
     for name in programs.TABLE1:
-        row, plan, arrays = run_kernel(name, SMOKE_SCALES[name],
-                                       trace_mode="interp")
-        row_c, plan_c, _ = run_kernel(name, SMOKE_SCALES[name],
-                                      trace_mode="compiled")
-        np.testing.assert_array_equal(
-            plan.req_wave, plan_c.req_wave,
-            err_msg=f"{name}: waves diverged across trace modes",
-        )
+        row, plan, arrays = run_kernel(name, SMOKE_SCALES[name])
         checks = frontier_crosschecks(name, plan, arrays)
         print(f"{name:12s} smoke OK: waves={row['n_waves']} "
               f"par={row['parallelism']}x"
               + (f" [{', '.join(checks)}]" if checks else ""), flush=True)
     for name in programs.SPEC_KERNELS:
-        row, _, _ = run_kernel(name, SMOKE_SCALES[name], trace_mode="auto")
+        row, _, _ = run_kernel(name, SMOKE_SCALES[name])
         print(f"{name:12s} smoke OK: waves={row['n_waves']} "
               f"par={row['parallelism']}x (speculative)", flush=True)
     n = len(programs.TABLE1) + len(programs.SPEC_KERNELS)

@@ -70,7 +70,7 @@ def run_table(scales=None, validate=False, engine="event", trace_mode="auto"):
 
 
 # single implementation lives in the importable library layer
-from repro.launch.analysis import harmonic_mean  # noqa: E402
+from repro.dse.summary import harmonic_mean  # noqa: E402
 
 
 def summarize(rows):

@@ -5,9 +5,6 @@ Prints ``name,us_per_call,derived`` CSV rows:
   * fig5_pruning   — hazard-pair pruning on the FFT code (Fig. 5)
   * forwarding_*   — §7.3.2 store-to-load forwarding impact
   * wave_*         — TPU wave-executor parallelism (Fig. 1c analogue)
-  * kernel_*       — Pallas kernel microbenches (interpret mode walltime;
-    shape-correctness is the signal on CPU, not speed)
-  * roofline summary — dry-run cell counts (full tables in EXPERIMENTS.md)
 """
 
 from __future__ import annotations
@@ -100,60 +97,6 @@ def bench_waves(emit):
         )
 
 
-def bench_kernels(emit):
-    import jax
-    import jax.numpy as jnp
-
-    key = jax.random.PRNGKey(0)
-    ks = jax.random.split(key, 8)
-
-    from repro.kernels.du_hazard.ops import hazard_frontier
-    src = jnp.sort(jax.random.randint(ks[0], (4096,), 0, 2048))
-    dst = jax.random.randint(ks[1], (4096,), 0, 2048)
-    us, _ = _t(
-        lambda: jax.block_until_ready(
-            hazard_frontier(src, dst, interpret=True)
-        ), reps=2,
-    )
-    emit("kernel_du_hazard_4k", us, "interpret=True")
-
-    from repro.kernels.moe_group_mm.kernel import group_matmul
-    x = jax.random.normal(ks[2], (512, 64))
-    w = jax.random.normal(ks[3], (8, 64, 64)) * 0.1
-    be = jax.random.randint(ks[4], (16,), 0, 8).astype(jnp.int32)
-    us, _ = _t(
-        lambda: jax.block_until_ready(
-            group_matmul(x, w, be, block_t=32, interpret=True)
-        ), reps=2,
-    )
-    emit("kernel_moe_group_mm", us, "8e_512t_interpret")
-
-    from repro.kernels.attention.ops import flash_attention
-    q = jax.random.normal(ks[5], (4, 256, 64))
-    k = jax.random.normal(ks[6], (4, 256, 64))
-    v = jax.random.normal(ks[7], (4, 256, 64))
-    us, _ = _t(
-        lambda: jax.block_until_ready(
-            flash_attention(q, k, v, block_q=64, block_k=64, interpret=True)
-        ), reps=2,
-    )
-    emit("kernel_flash_attention", us, "4x256x64_interpret")
-
-
-def bench_roofline_summary(emit):
-    from benchmarks import roofline
-
-    cells = roofline.load()
-    if not cells:
-        emit("roofline_cells", 0, "missing_run_dryrun_first")
-        return
-    s = roofline.summary(cells)
-    emit(
-        "roofline_cells", 0,
-        f"ok={s['ok']};skipped={s['skipped']};errors={s['errors']}",
-    )
-
-
 def main(argv=None) -> None:
     import argparse
 
@@ -194,8 +137,6 @@ def main(argv=None) -> None:
     bench_pruning(emit)
     bench_forwarding(emit)
     bench_waves(emit)
-    bench_kernels(emit)
-    bench_roofline_summary(emit)
 
 
 if __name__ == "__main__":

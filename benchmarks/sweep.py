@@ -11,7 +11,7 @@ Produces the evidence file committed as ``BENCH_DSE.json``:
   * **bit-identity verification**: every sweep point's SimResult
     (cycles, DRAM traffic, forwards, and a sha256 of every final
     array) equals its standalone call,
-  * per-kernel speedups/Pareto sizings (``launch.analysis``) and the
+  * per-kernel speedups/Pareto sizings (``dse.summary``) and the
     config-batched §5.5 slack profile.
 
 Sweep-service flags (DESIGN.md §13):
@@ -22,7 +22,7 @@ Sweep-service flags (DESIGN.md §13):
   * ``--resume`` re-plans from the surviving ``--cache-dir`` after an
     interrupted run (only missing unique runs execute),
   * ``--stream`` prints each point as it lands plus the live partial
-    Pareto front size (``launch.analysis.ParetoTracker``),
+    Pareto front size (``dse.summary.ParetoTracker``),
   * ``--shard-check N`` re-runs the sweep as N shards in fresh caches
     and asserts ``merge_results`` equals the single-host result
     bit-for-bit (the nightly 594-point gate uses ``--shard-check 2``),
@@ -57,7 +57,7 @@ import numpy as np
 from benchmarks.paper_table1 import scaled
 from repro import dse
 from repro.core import programs, simulator
-from repro.launch import analysis
+from repro.dse import summary
 
 # DU sizings x calibration knobs. The last three vary parameters only
 # some modes read (dse.spec.MODE_SIM_FIELDS): sta-ii-* move the STA
@@ -227,7 +227,7 @@ def check_stream_pareto(spec) -> dict:
     """Drive the sweep through ``on_point`` feeding a ParetoTracker;
     assert every streaming prefix front equals the batch
     ``pareto_front`` recompute over the rows seen so far."""
-    tracker = analysis.ParetoTracker()
+    tracker = summary.ParetoTracker()
     rows: list = []
 
     def on_point(pr):
@@ -238,7 +238,7 @@ def check_stream_pareto(spec) -> dict:
         }
         rows.append(row)
         tracker.update(row)
-        batch = [rows[i] for i in analysis.pareto_front(rows)]
+        batch = [rows[i] for i in summary.pareto_front(rows)]
         assert tracker.front() == batch, (
             f"streaming front diverged at point {len(rows)}"
         )
@@ -316,7 +316,7 @@ def main(argv=None):
     print(f"sweep: {len(points)} points over {len(programs.TABLE1)} kernels "
           f"at scales {scales}", flush=True)
 
-    tracker = analysis.ParetoTracker()
+    tracker = summary.ParetoTracker()
 
     def stream_cb(pr):
         row = {"cycles": pr.result.cycles,
@@ -420,7 +420,7 @@ def main(argv=None):
         "speedup_warm": round(base_wall / max(walls["warm_s"], 1e-9), 1),
         "target_speedup": a.target_speedup,
         "all_points_bit_identical": identical,
-        "summary": analysis.summarize_sweep(rows),
+        "summary": summary.summarize_sweep(rows),
         "forward_slack_profile": res.profile,
         "group_stats": res.groups,
     }

@@ -79,9 +79,9 @@ class RunConfig:
       * ``mode`` — evaluated system (simulate/engines, DSE).
       * ``engine`` — timing engine for the dynamic modes
         (simulate/engines, DSE; STA provably ignores it).
-      * ``trace_mode`` — AGU/CU front-end (simulate, executor, DSE;
-        proven bit-identical across values, so excluded from the DSE
-        result identity).
+      * ``trace_mode`` — AGU/CU front-end (simulate, DSE; proven
+        bit-identical across values, so excluded from the DSE result
+        identity; the executor's stream is the oracle walk's).
       * ``speculation`` — loss-of-decoupling policy (simulate, DSE;
         the executor admits loss-of-decoupling programs whatever it
         says, since its results do not depend on it).

@@ -47,17 +47,14 @@ The module is split along the backend seam (DESIGN.md §2):
     bit-identical to the sequential oracle.
 
 ``frontier_merge`` is the vectorized monotonic-streams primitive shared
-with the Pallas kernels and the MoE dispatch path.
+with the Pallas kernels.
 
-``trace_mode`` (default ``"auto"``) selects where the program-order
-request stream's op ids / addresses / kinds come from: the AGU trace
-compiler (``schedule.trace_program``) plus one lexsort of polyhedral
-2d+1 keys, with the oracle walk supplying the reference value/valid
-stream; ``"interp"`` keeps the original pure-hook path, which ``"auto"``
-also takes for a program with a speculative PE. The oracle walk
-runs in full either way — backends *compute* store values through the
-op tables, and the walk's values are the per-request reference that
-pins any divergence to the first offending request.
+The program-order request stream (op ids, addresses, kinds) is the
+oracle walk's own, recorded by its hook;
+``schedule.program_order_stream``, the paper's schedule-order merge of
+the AGU traces, is the reference the tests hold it to. Backends *compute* store values through the op
+tables, and the walk's values are the per-request reference that pins
+any divergence to the first offending request.
 """
 
 from __future__ import annotations
@@ -202,64 +199,10 @@ def frontier_merge(src_addr: np.ndarray, dst_addr: np.ndarray) -> np.ndarray:
     return np.searchsorted(src_addr, dst_addr, side="right")
 
 
-def _trace_stream(
-    program: ir.Program,
-    dae,
-    arrays: dict[str, np.ndarray],
-    params: dict[str, int],
-    trace_mode: str,
-) -> tuple[list[str], list[int], list[bool]]:
-    """Program-order (op id, address, is_store) stream from AGU traces.
-
-    Global program order is lexicographic on the polyhedral 2d+1 key —
-    static body positions and the §4 never-reset counters interleaved,
-    with the op's own body position last. Supplies everything except
-    values/valid bits, which only the oracle walk can produce.
-    ``build_wave_plan`` calls it for decoupled programs only: a
-    speculative PE's post-squash stream is the walk's own (see there).
-    """
-    from repro.core import schedule as schedlib
-
-    traces = schedlib.trace_program(
-        program, dae, arrays, params, mode=trace_mode,
-    )
-    loop_pos, op_pos = program.static_positions()
-    op_path = {op.id: path for op, path in program.mem_ops()}
-    ops = sorted(traces)
-    if not ops:
-        return [], [], []
-    width = 2 * max(tr.depth for tr in traces.values()) + 1
-    mats = []
-    for op_id in ops:
-        tr = traces[op_id]
-        path = op_path[op_id]
-        key = np.full((tr.n_req, width), -1, dtype=np.int64)
-        for j in range(tr.depth):
-            key[:, 2 * j] = loop_pos[id(path[j])]
-            key[:, 2 * j + 1] = tr.sched[:, j]
-        key[:, 2 * tr.depth] = op_pos[op_id]
-        mats.append(key)
-    stacked = np.concatenate(mats, axis=0)
-    order = np.lexsort(stacked.T[::-1])
-    flat_op: list[str] = []
-    flat_addr = np.concatenate([traces[o].addr for o in ops])
-    flat_store: list[bool] = []
-    for op_id in ops:
-        tr = traces[op_id]
-        flat_op.extend([op_id] * tr.n_req)
-        flat_store.extend([tr.is_store] * tr.n_req)
-    return (
-        [flat_op[i] for i in order],
-        flat_addr[order].tolist(),
-        [flat_store[i] for i in order],
-    )
-
-
 def build_wave_plan(
     program: ir.Program,
     arrays: dict[str, np.ndarray],
     params: Optional[dict[str, int]] = None,
-    trace_mode=cfglib.UNSET,
     speculation=cfglib.UNSET,
     predictor=cfglib.UNSET,
     batch_waves=cfglib.UNSET,
@@ -269,25 +212,20 @@ def build_wave_plan(
 ) -> WavePlan:
     """Run the AGU/CU front-end and emit the backend-consumable plan.
 
-    One hooked oracle walk supplies (a) the reference value/valid
-    streams, (b) the op-table environment slots via the ``aux_exprs``
-    interpreter hook, (c) the dep alignment maps (most recent request
-    of each feeding load at every store request). For a decoupled
-    program ``trace_mode != "interp"`` builds the op/addr/kind streams
-    through the trace compiler and asserts they agree with the walk.
+    One hooked oracle walk supplies (a) the program-order op/addr/kind
+    request stream, which ``schedule.program_order_stream`` would build
+    from the AGU traces request for request, (b) the reference
+    value/valid streams, (c) the op-table environment slots via the
+    ``aux_exprs`` interpreter hook, (d) the dep alignment maps (most
+    recent request of each feeding load at every store request).
 
     Loss-of-decoupling programs (load-dependent trips/addresses,
     DESIGN.md §10) are admitted with no option set: the decoupling pass
     marks their PEs speculative, as ``simulate(speculation="auto")``
     does, and the wave partition works off the *true* post-squash
-    request stream — phantom squash traffic is a DU-timing artifact and
-    has no wave-executor analogue. That stream is the walk's own, in
-    program order, so under ``"auto"`` the walk's hook supplies it and
-    no speculative AGU trace runs (``"compiled"`` still raises
-    ``TraceCompileError``; gates and phantoms stay ``simulate()``'s).
-    The ``repro.plan.trace`` span's ``from_walk`` stat is 1 where the
-    walk supplied the stream. A decoupled program gets no
-    speculative PE. ``speculation`` and ``predictor``
+    request stream, the walk's own — phantom squash traffic and gates
+    are DU-timing artifacts and stay ``simulate()``'s. A decoupled
+    program gets no speculative PE. ``speculation`` and ``predictor``
     (``dae.PREDICTORS``) are accepted for API uniformity with
     ``simulate()``: the post-squash streams are identical under every
     value of either, so the plan depends on neither.
@@ -312,17 +250,16 @@ def build_wave_plan(
     pop ``k``) — ``validate_plan`` asserts both per edge.
 
     ``config=`` accepts a ``repro.core.config.RunConfig``; the
-    executor consumes its ``trace_mode``/``predictor``/``batch_waves``/
-    ``fifo_depth``/``symbolic_admission`` fields and ignores the
-    simulator-only ones (``mode``, ``engine``, ``speculation``, ...). A
+    executor consumes its ``predictor``/``batch_waves``/``fifo_depth``/
+    ``symbolic_admission`` fields and ignores the simulator-only ones
+    (``mode``, ``engine``, ``trace_mode``, ``speculation``, ...). A
     conflicting explicit kwarg raises ``config.ConfigConflict``.
     """
     cfg = cfglib.resolve(
-        config, trace_mode=trace_mode, speculation=speculation,
-        predictor=predictor, batch_waves=batch_waves,
-        symbolic_admission=symbolic_admission,
+        config, speculation=speculation, predictor=predictor,
+        batch_waves=batch_waves, symbolic_admission=symbolic_admission,
     )
-    trace_mode, predictor = cfg.trace_mode, cfg.predictor
+    predictor = cfg.predictor
     batch_waves, symbolic_admission = cfg.batch_waves, cfg.symbolic_admission
     # fifo_depth=None in a config means "default" (4 here, matching
     # SimParams.fifo_depth) — only a real config value can conflict
@@ -337,8 +274,8 @@ def build_wave_plan(
     params = params or {}
     with trace.span("plan"):
         return _plan(
-            program, arrays, params, trace_mode, predictor, batch_waves,
-            fifo_depth, symbolic_admission,
+            program, arrays, params, predictor, batch_waves, fifo_depth,
+            symbolic_admission,
         )
 
 
@@ -367,7 +304,6 @@ def _plan(
     program: ir.Program,
     arrays: dict[str, np.ndarray],
     params: dict[str, int],
-    trace_mode: str,
     predictor: str,
     batch_waves: bool,
     fifo_depth: int,
@@ -428,12 +364,7 @@ def _plan(
         op_id: {ld: [] for ld in t.deps} for op_id, t in tables.items()
     }
     counts: dict[str, int] = {}
-    # where the walk supplies the program-order op/addr/kind stream: under
-    # "interp", and for a speculative program under "auto" (its AGU trace
-    # would rebuild the same post-squash stream)
-    from_walk = trace_mode == "interp" or (
-        bool(dae.spec) and trace_mode == "auto"
-    )
+    # the program-order op/addr/kind stream, as the walk meets it
     req_op_l: list[str] = []
     req_addr_l: list[int] = []
     req_store_l: list[bool] = []
@@ -477,10 +408,9 @@ def _plan(
                 rows.append(counts.get(ld, 0) - 1)
         else:
             counts[op_id] = counts.get(op_id, 0) + 1
-        if from_walk:
-            req_op_l.append(op_id)
-            req_addr_l.append(addr)
-            req_store_l.append(is_store)
+        req_op_l.append(op_id)
+        req_addr_l.append(addr)
+        req_store_l.append(is_store)
 
     fifo_loop_hook = None
     if fifo_spec:
@@ -546,28 +476,14 @@ def _plan(
         walk.set(requests=n_real[0], compiled=int(compiled),
                  walk_compiles=int(compiles))
 
-    if trace_mode != "interp":
-        with trace.span("plan.trace", from_walk=int(from_walk)):
-            if not from_walk:
-                # a speculative PE under "compiled" raises
-                # TraceCompileError here
-                req_op_l, req_addr_l, req_store_l = _trace_stream(
-                    program, dae, arrays, params, trace_mode,
-                )
-                n_oracle = sum(len(v) for v in per_op_vv.values())
-                assert n_oracle == len(req_op_l), (
-                    f"trace stream has {len(req_op_l)} requests, oracle "
-                    f"walk {n_oracle} — trace compiler divergence"
-                )
-
     with trace.span("plan.streams"):
         op_ids = [op.id for op, _ in program.mem_ops()]
         op_array = {op.id: op.array for op, _ in program.mem_ops()}
         op_is_store = {op.id: op.is_store for op, _ in program.mem_ops()}
 
         # merge the FIFO token events into the request stream as pseudo
-        # requests on the edge's circular slots (module docstring) — after
-        # the trace-count assert, which covers real requests only
+        # requests on the edge's circular slots (build_wave_plan's
+        # docstring)
         push_k: dict[int, int] = {}
         if fifo_events:
             pop_k: dict[int, int] = {}
@@ -1118,7 +1034,6 @@ def execute(
     program: ir.Program,
     arrays: dict[str, np.ndarray],
     params: Optional[dict[str, int]] = None,
-    trace_mode=cfglib.UNSET,
     speculation=cfglib.UNSET,
     predictor=cfglib.UNSET,
     backend=cfglib.UNSET,
@@ -1141,13 +1056,13 @@ def execute(
 
     Both compute store values through the op tables and are asserted
     request-exact against the oracle reference stream; final arrays are
-    bit-identical to ``loopir.interpret`` for every Table-1 kernel in
-    both trace modes (tests/test_pallas_parity.py).
+    bit-identical to ``loopir.interpret`` for every Table-1 kernel
+    (tests/test_pallas_parity.py). The plan's request stream is the
+    oracle walk's (``build_wave_plan``).
 
     Loss-of-decoupling programs (load-dependent trips/addresses,
-    DESIGN.md §10) run with no option set, through the speculative AGU
-    trace (``build_wave_plan``); decoupled programs get no speculative
-    PE. ``speculation`` and ``predictor`` (``dae.PREDICTORS``)
+    DESIGN.md §10) run with no option set; decoupled programs get no
+    speculative PE. ``speculation`` and ``predictor`` (``dae.PREDICTORS``)
     are accepted for API uniformity with ``simulate()``: final arrays
     and the wave partition are identical under every value of either
     (tests/test_speculation.py pins this).
@@ -1169,13 +1084,13 @@ def execute(
 
     ``config=`` accepts a ``repro.core.config.RunConfig``; the
     executor consumes every field except the simulator-only ``mode``/
-    ``engine``/``speculation``/``spec_runahead``/``fifo_latency``/
-    ``static_prune``. A conflicting explicit kwarg raises
+    ``engine``/``trace_mode``/``speculation``/``spec_runahead``/
+    ``fifo_latency``/``static_prune``. A conflicting explicit kwarg raises
     ``config.ConfigConflict``. Final arrays are bit-identical between
     the two spellings.
     """
     cfg = cfglib.resolve(
-        config, trace_mode=trace_mode, speculation=speculation,
+        config, speculation=speculation,
         predictor=predictor, backend=backend, batch_waves=batch_waves,
         symbolic_admission=symbolic_admission, validate_hints=validate_hints,
     )

@@ -269,6 +269,57 @@ def trace_program(
     return out
 
 
+def program_order_stream(
+    program: ir.Program,
+    dae: daelib.DAEResult,
+    arrays: dict[str, np.ndarray],
+    params: Optional[dict[str, int]] = None,
+    mode: str = "auto",
+) -> tuple[list[str], list[int], list[bool]]:
+    """Program-order (op id, address, is_store) stream merged from the
+    AGU traces of every PE (``trace_program``).
+
+    Global program order is lexicographic on the polyhedral 2d+1 key —
+    static body positions and the §4 never-reset counters interleaved,
+    with the op's own body position last. This is the paper's claim
+    that the decoupled AGUs, merged by schedule, replay the sequential
+    program's requests; the wave executor takes its stream from the
+    oracle walk instead, and the tests hold the two equal request for
+    request.
+    """
+    traces = trace_program(program, dae, arrays, params, mode=mode)
+    loop_pos, op_pos = program.static_positions()
+    op_path = {op.id: path for op, path in program.mem_ops()}
+    ops = sorted(traces)
+    if not ops:
+        return [], [], []
+    width = 2 * max(tr.depth for tr in traces.values()) + 1
+    mats = []
+    for op_id in ops:
+        tr = traces[op_id]
+        path = op_path[op_id]
+        key = np.full((tr.n_req, width), -1, dtype=np.int64)
+        for j in range(tr.depth):
+            key[:, 2 * j] = loop_pos[id(path[j])]
+            key[:, 2 * j + 1] = tr.sched[:, j]
+        key[:, 2 * tr.depth] = op_pos[op_id]
+        mats.append(key)
+    stacked = np.concatenate(mats, axis=0)
+    order = np.lexsort(stacked.T[::-1])
+    flat_op: list[str] = []
+    flat_addr = np.concatenate([traces[o].addr for o in ops])
+    flat_store: list[bool] = []
+    for op_id in ops:
+        tr = traces[op_id]
+        flat_op.extend([op_id] * tr.n_req)
+        flat_store.extend([tr.is_store] * tr.n_req)
+    return (
+        [flat_op[i] for i in order],
+        flat_addr[order].tolist(),
+        [flat_store[i] for i in order],
+    )
+
+
 def _static_op_meta(
     pe: daelib.PE,
 ) -> tuple[list[tuple], dict[str, int], dict[str, bool]]:

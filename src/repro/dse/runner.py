@@ -129,7 +129,7 @@ class SweepResult:
     stats: Optional[SweepStats] = None
 
     def rows(self) -> list:
-        """Flat per-point dict rows (for ``launch.analysis`` helpers).
+        """Flat per-point dict rows (for the ``dse.summary`` helpers).
         Sharded results emit rows only for the shard's own points."""
         out = []
         for pr in self.points:
@@ -660,7 +660,7 @@ def iter_points(
     its group completes (completion order — deterministic for
     ``workers=1``, interleaved otherwise; the *set* of rows is always
     identical to ``sweep().points``). Use for live dashboards /
-    partial Pareto fronts (``launch.analysis.ParetoTracker``) without
+    partial Pareto fronts (``dse.summary.ParetoTracker``) without
     waiting for the full sweep."""
     return (
         pr

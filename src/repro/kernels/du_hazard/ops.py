@@ -8,7 +8,7 @@ wait for the equal-address producer) vs the strict-precedence variant
 (the multi-array shape of a fused program).
 ``hazard_frontier_ref`` / ``hazard_frontier_batch_ref`` — pure-jnp
 oracles.
-``wave_partition`` — composition used by the fused executor / MoE path:
+``wave_partition`` — the wave partition composed from frontiers:
 given per-pair frontiers, assign each consumer request the earliest wave
 in which all its producers have committed.
 """

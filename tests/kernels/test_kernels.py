@@ -163,100 +163,6 @@ def test_min_lookback_runs():
 
 
 # ---------------------------------------------------------------------------
-# moe_group_mm
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize(
-    "e,din,dout,bt,nb",
-    [
-        (4, 32, 48, 16, 8),
-        pytest.param(8, 16, 16, 8, 16, marks=pytest.mark.slow),
-    ],
-)
-@pytest.mark.parametrize("dtype", [jnp.float32])
-def test_group_matmul_sweep(e, din, dout, bt, nb, dtype):
-    from repro.kernels.moe_group_mm.kernel import group_matmul
-    from repro.kernels.moe_group_mm.ref import group_matmul_ref
-
-    k1, k2, k3 = keys(3)
-    x = jax.random.normal(k1, (nb * bt, din), dtype)
-    w = jax.random.normal(k2, (e, din, dout), dtype) * 0.1
-    be = jax.random.randint(k3, (nb,), 0, e).astype(jnp.int32)
-    got = group_matmul(x, w, be, block_t=bt, interpret=True)
-    exp = group_matmul_ref(x, w, be, block_t=bt)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(exp), atol=1e-5)
-
-
-@pytest.mark.slow
-def test_moe_ffn_dropless_vs_dense_oracle():
-    from repro.kernels.moe_group_mm.ops import moe_ffn
-
-    k1, k2, k3, k4, k5 = keys(5)
-    T, dm, dff, E, K = 24, 16, 32, 4, 2
-    x = jax.random.normal(k1, (T, dm))
-    logits = jax.random.normal(k2, (T, E))
-    wi = jax.random.normal(k3, (E, dm, dff)) * 0.1
-    wg = jax.random.normal(k4, (E, dm, dff)) * 0.1
-    wo = jax.random.normal(k5, (E, dff, dm)) * 0.1
-    out_k = moe_ffn(x, logits, wi, wg, wo, top_k=K, use_kernel=True,
-                    block_t=8, interpret=True)
-    out_r = moe_ffn(x, logits, wi, wg, wo, top_k=K, use_kernel=False,
-                    block_t=8)
-    np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r), atol=1e-4)
-
-    probs = jax.nn.softmax(logits, -1)
-    tp, te = jax.lax.top_k(probs, K)
-    tp = tp / tp.sum(-1, keepdims=True)
-    dense = np.zeros((T, dm), np.float32)
-    for kk in range(K):
-        for t in range(T):
-            e = int(te[t, kk])
-            h = jax.nn.silu(x[t] @ wg[e]) * (x[t] @ wi[e])
-            dense[t] += float(tp[t, kk]) * np.asarray(h @ wo[e])
-    np.testing.assert_allclose(np.asarray(out_r), dense, atol=1e-3)
-
-
-# ---------------------------------------------------------------------------
-# attention
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize(
-    "s,d,causal",
-    [
-        (64, 32, True),
-        pytest.param(128, 16, False, marks=pytest.mark.slow),
-    ],
-)
-def test_flash_attention_kernel_sweep(s, d, causal):
-    from repro.kernels.attention.ops import flash_attention, flash_attention_ref
-
-    k1, k2, k3 = keys(3)
-    q = jax.random.normal(k1, (4, s, d), jnp.float32)
-    k = jax.random.normal(k2, (4, s, d), jnp.float32)
-    v = jax.random.normal(k3, (4, s, d), jnp.float32)
-    got = flash_attention(q, k, v, causal=causal, sm_scale=d ** -0.5,
-                          block_q=16, block_k=16, interpret=True)
-    exp = flash_attention_ref(q, k, v, causal=causal, sm_scale=d ** -0.5)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(exp), atol=1e-4)
-
-
-def test_decode_attention_kernel():
-    from repro.kernels.attention.ops import decode_attention, decode_attention_ref
-
-    k1, k2, k3 = keys(3)
-    q = jax.random.normal(k1, (4, 1, 32))
-    kc = jax.random.normal(k2, (4, 64, 32))
-    vc = jax.random.normal(k3, (4, 64, 32))
-    lengths = jnp.array([1, 17, 33, 64])
-    got = decode_attention(q, kc, vc, lengths, sm_scale=0.2, block_k=16,
-                           interpret=True)
-    exp = decode_attention_ref(q, kc, vc, lengths, sm_scale=0.2)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(exp), atol=1e-4)
-
-
-# ---------------------------------------------------------------------------
 # csr_spmv + histogram
 # ---------------------------------------------------------------------------
 
@@ -301,61 +207,3 @@ def test_hist_add_fused_matches_numpy():
     exp = np.bincount(d1, minlength=32) + np.bincount(d2, minlength=32)
     np.testing.assert_allclose(np.asarray(got), exp)
 
-
-# ---------------------------------------------------------------------------
-# ssm_scan (fused Mamba selective scan)
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("s,di,n,chunk,bd", [(64, 64, 8, 16, 32),
-                                             (128, 128, 16, 32, 128)])
-def test_ssm_scan_kernel_sweep(s, di, n, chunk, bd):
-    from repro.kernels.ssm_scan.ops import ssm_scan, ssm_scan_ref
-
-    k1, k2, k3, k4 = keys(4)
-    xi = jax.random.normal(k1, (s, di)) * 0.5
-    dt = jax.nn.softplus(jax.random.normal(k2, (s, di)))
-    bm = jax.random.normal(k3, (s, n)) * 0.5
-    cm = jax.random.normal(k4, (s, n)) * 0.5
-    a_neg = -jnp.exp(jax.random.normal(keys(5)[4], (di, n)) * 0.3)
-    got = ssm_scan(xi, dt, bm, cm, a_neg, chunk=chunk, block_d=bd,
-                   interpret=True)
-    exp = ssm_scan_ref(xi, dt, bm, cm, a_neg)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(exp),
-                               rtol=1e-4, atol=1e-4)
-
-
-@pytest.mark.slow
-def test_ssm_scan_matches_model_path():
-    """The kernel agrees with the model's chunked jnp scan end to end."""
-    import dataclasses
-
-    from repro.configs import base as configs
-    from repro.kernels.ssm_scan.ops import ssm_scan_batched
-    from repro.models import ssm as S
-    from repro.models.layers import FP32
-
-    cfg = dataclasses.replace(
-        configs.get("falcon-mamba-7b").reduced(), d_model=32, ssm_chunk=16
-    )
-    di, n = cfg.expand * 32, cfg.ssm_state
-    key = jax.random.PRNGKey(9)
-    p = S.mamba_init(key, cfg, FP32)
-    b, s = 2, 64
-    xi = jax.random.normal(key, (b, s, di)) * 0.5
-
-    # model path
-    h0 = jnp.zeros((b, di, n), jnp.float32)
-    y_model, _ = S._mamba1_chunked(p, xi, cfg, h0, cfg.ssm_chunk)
-
-    # kernel path: same projections
-    bc = xi @ p["w_bc"]
-    bm, cm = bc[..., :n], bc[..., n:]
-    dt = jax.nn.softplus(xi @ p["w_dt"] + p["dt_bias"][None, None])
-    a_neg = -jnp.exp(p["a_log"])
-    y_kern = ssm_scan_batched(
-        xi, dt, bm, cm, a_neg, chunk=16, block_d=di, interpret=True
-    )
-    np.testing.assert_allclose(
-        np.asarray(y_kern), np.asarray(y_model), rtol=1e-4, atol=1e-4
-    )

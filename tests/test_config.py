@@ -95,11 +95,11 @@ def test_execute_config_bit_identical_to_kwargs():
     prog, arrays, params = b.make(48)
     legacy = executor.execute(
         prog, {k: v.copy() for k, v in arrays.items()}, params,
-        trace_mode="interp", batch_waves=False,
+        fifo_depth=2, batch_waves=False,
     )
     cfg = executor.execute(
         prog, {k: v.copy() for k, v in arrays.items()}, params,
-        config=RunConfig(trace_mode="interp", batch_waves=False),
+        config=RunConfig(fifo_depth=2, batch_waves=False),
     )
     for k in legacy.arrays:
         assert np.array_equal(legacy.arrays[k], cfg.arrays[k])

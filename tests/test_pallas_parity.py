@@ -1,6 +1,6 @@
 """Pallas wave-backend parity: the hardware execution path against the
-simulator and the sequential oracle, across kernel × trace-mode ×
-speculation, plus the WavePlan contract and the op-table factoring.
+simulator and the sequential oracle, across kernel × speculation, plus
+the WavePlan contract and the op-table factoring.
 
 This is the conformance suite the backend's claim rests on (DESIGN.md
 §2): "Pallas hardware path agrees with simulate()" — final arrays
@@ -27,11 +27,6 @@ SCALES = {
     "strided_scan": 24,
 }
 
-TRACE_MODES = {name: ("interp", "compiled") for name in programs.TABLE1}
-# speculative streams are interpreter-built; "compiled" raises by design
-TRACE_MODES.update({name: ("interp", "auto")
-                    for name in programs.SPEC_KERNELS})
-
 ALL_KERNELS = tuple(programs.TABLE1) + tuple(programs.SPEC_KERNELS)
 
 
@@ -43,7 +38,7 @@ def _make(name):
 
 
 # ---------------------------------------------------------------------------
-# the full kernel × trace-mode matrix, arrays exact + waves pinned
+# every kernel through the device path, arrays exact + waves pinned
 # ---------------------------------------------------------------------------
 
 
@@ -53,37 +48,22 @@ def test_pallas_backend_matrix(name):
     oracle = ir.interpret(prog, arrays, params)
     sim = simulator.simulate(prog, arrays, params, mode="FUS2",
                              engine="event", speculation=spec)
-    ref_plan = None
-    for tm in TRACE_MODES[name]:
-        res = executor.execute(
-            prog, arrays, params, trace_mode=tm, speculation=spec,
-            backend="pallas",
+    res = executor.execute(
+        prog, arrays, params, speculation=spec, backend="pallas",
+    )
+    for k in oracle:
+        np.testing.assert_array_equal(
+            res.arrays[k], oracle[k],
+            err_msg=f"{name}: backend != oracle on {k}",
         )
-        for k in oracle:
-            np.testing.assert_array_equal(
-                res.arrays[k], oracle[k],
-                err_msg=f"{name}/{tm}: backend != oracle on {k}",
-            )
-        for k in sim.arrays:
-            np.testing.assert_array_equal(
-                res.arrays[k], sim.arrays[k],
-                err_msg=f"{name}/{tm}: backend != simulate() on {k}",
-            )
-        # wave counts pinned against WaveStats, identical across modes
-        assert res.stats.n_waves == res.plan.stats.n_waves
-        assert res.stats.n_requests == len(res.waves)
-        if ref_plan is None:
-            ref_plan = res.plan
-        else:
-            np.testing.assert_array_equal(
-                res.plan.req_wave, ref_plan.req_wave,
-                err_msg=f"{name}: wave partition diverged across "
-                f"trace modes",
-            )
-            np.testing.assert_array_equal(
-                res.plan.req_flat, ref_plan.req_flat,
-                err_msg=f"{name}: addresses diverged across trace modes",
-            )
+    for k in sim.arrays:
+        np.testing.assert_array_equal(
+            res.arrays[k], sim.arrays[k],
+            err_msg=f"{name}: backend != simulate() on {k}",
+        )
+    # wave counts pinned against WaveStats
+    assert res.stats.n_waves == res.plan.stats.n_waves
+    assert res.stats.n_requests == len(res.waves)
 
 
 @pytest.mark.parametrize("name", ALL_KERNELS)

@@ -269,9 +269,7 @@ def test_executor_runs_spec_kernels(name):
 
     prog, arrays, params = programs.get(name).make(SCALES[name])
     ra = executor.execute(prog, arrays, params, speculation="auto")
-    rb = executor.execute(
-        prog, arrays, params, speculation="auto", trace_mode="interp"
-    )
+    rb = executor.execute(prog, arrays, params, predictor="stride")
     # the executor admits loss-of-decoupling programs with no option
     # set: ``speculation`` is a simulate()-only field
     rd = executor.execute(prog, arrays, params)
@@ -314,45 +312,68 @@ _GENERATORS = {
     "stride": (strat.random_stride_spec_program, 3000),
     "context": (strat.random_context_spec_program, 4000),
 }
+# small instances of the decoupled registry kernels
+_DECOUPLED_SCALES = {
+    "RAWloop": 48, "WARloop": 48, "WAWloop": 48, "bnn": 8, "pagerank": 16,
+    "fft": 32, "matpower": 8, "hist+add": 48, "tanh+spmv": 32,
+    "stream_dot": 16, "filter_pipe": 16, "stream_join": 16,
+}
+# (id, make, AGU trace mode of the reference, speculative)
 _STREAM_CASES = (
-    [(f"kernel-{n}", lambda n=n: programs.get(n).make(SCALES[n]))
-     for n in programs.SPEC_KERNELS]
-    + [(f"bfs_rmat-{sc}-{sd}", lambda sc=sc, sd=sd: _bfs_rmat(sc, sd))
-       for sc, sd in ((5, 0), (6, 1), (6, 2))]
+    [(f"kernel-{n}", lambda n=n: programs.get(n).make(SCALES[n]), "auto",
+      True) for n in programs.SPEC_KERNELS]
+    + [(f"bfs_rmat-{sc}-{sd}", lambda sc=sc, sd=sd: _bfs_rmat(sc, sd),
+        "auto", True) for sc, sd in ((5, 0), (6, 1), (6, 2))]
     + [(f"{g}-{seed}",
         lambda g=g, seed=seed: _GENERATORS[g][0](
-            np.random.default_rng(_GENERATORS[g][1] + seed)))
+            np.random.default_rng(_GENERATORS[g][1] + seed)), "auto", True)
        for g in _GENERATORS for seed in range(4)]
+    # the compiled AGU refuses FIFO consumers, so stream kernels are
+    # held to the interpreted trace only
+    + [(f"kernel-{n}-{mode}",
+        lambda n=n: programs.get(n).make(_DECOUPLED_SCALES[n]), mode, False)
+       for n in programs.TABLE1 for mode in ("interp", "compiled")]
+    + [(f"kernel-{n}-interp",
+        lambda n=n: programs.get(n).make(_DECOUPLED_SCALES[n]), "interp",
+        False) for n in programs.STREAM_KERNELS]
 )
 
 
 @pytest.mark.parametrize(
-    "make", [m for _, m in _STREAM_CASES], ids=[i for i, _ in _STREAM_CASES]
+    "make, mode, speculative", [c[1:] for c in _STREAM_CASES],
+    ids=[c[0] for c in _STREAM_CASES],
 )
-def test_spec_plan_stream_is_the_agu_trace(make):
-    """A speculative program's plan takes its op/addr/kind stream from
-    the oracle walk: that stream is the speculative AGU trace's, request
-    for request. Every other plan array is a function of this stream and
-    the walk's captures, and the executed arrays are the oracle's."""
+def test_plan_stream_is_the_agu_trace(make, mode, speculative):
+    """A plan takes its op/addr/kind stream from the oracle walk: that
+    stream is the schedule-order merge of the AGU traces
+    (``schedule.program_order_stream``), request for request, for
+    decoupled programs under either AGU mode and for speculative ones
+    (the post-squash stream). FIFO pseudo-requests (``~push``/``~pop``)
+    are the plan's own and are left out. Every other plan array is a
+    function of this stream and the walk's captures, and the executed
+    arrays are the oracle's."""
     prog, arrays, params = make()
     dae = daelib.decouple(prog, speculation="auto")
-    assert dae.spec
+    assert bool(dae.spec) == speculative
     res = executor.execute(prog, arrays, params)
     plan = res.plan
-    op_l, addr_l, store_l = executor._trace_stream(
-        prog, dae, arrays, params, "auto"
+    op_l, addr_l, store_l = schedlib.program_order_stream(
+        prog, dae, arrays, params, mode
     )
+    real = np.array([not plan.op_ids[i].startswith("~")
+                     for i in plan.req_op], dtype=bool)
     index = {o: i for i, o in enumerate(plan.op_ids)}
-    assert plan.n_requests == len(op_l) > 0
-    np.testing.assert_array_equal(plan.req_op, [index[o] for o in op_l])
-    np.testing.assert_array_equal(plan.req_addr, addr_l)
-    np.testing.assert_array_equal(plan.req_store, store_l)
+    assert int(real.sum()) == len(op_l) > 0
+    np.testing.assert_array_equal(plan.req_op[real],
+                                  [index[o] for o in op_l])
+    np.testing.assert_array_equal(plan.req_addr[real], addr_l)
+    np.testing.assert_array_equal(plan.req_store[real], store_l)
     np.testing.assert_array_equal(
-        plan.req_flat, [plan.base[plan.op_array[o]] + a
-                        for o, a in zip(op_l, addr_l)]
+        plan.req_flat[real], [plan.base[plan.op_array[o]] + a
+                              for o, a in zip(op_l, addr_l)]
     )
-    assert {o: n for o, n in plan.op_nreq.items() if n} == \
-        collections.Counter(op_l)
+    assert {o: n for o, n in plan.op_nreq.items()
+            if n and not o.startswith("~")} == collections.Counter(op_l)
     oracle = ir.interpret(prog, arrays, params)
     for k in oracle:
         np.testing.assert_array_equal(res.arrays[k], oracle[k], err_msg=k)

@@ -37,7 +37,7 @@ import pytest
 
 from repro import dse
 from repro.dse import runner as runner_mod
-from repro.launch import analysis
+from repro.dse import summary
 
 # chase_sum is a speculative kernel (pointer chase): "auto" keeps it
 # legal while folding to the "off" spec class on the other three, so
@@ -154,14 +154,14 @@ def test_on_point_and_iter_points_stream_everything():
 
 def test_pareto_tracker_prefix_equals_batch():
     rng = np.random.default_rng(7)
-    tracker = analysis.ParetoTracker()
+    tracker = summary.ParetoTracker()
     rows = []
     for i in range(200):
         row = {"cycles": int(rng.integers(1, 40)),
                "dram_bursts": int(rng.integers(1, 40)), "i": i}
         rows.append(row)
         tracker.update(row)
-        batch = [rows[j] for j in analysis.pareto_front(rows)]
+        batch = [rows[j] for j in summary.pareto_front(rows)]
         assert tracker.front() == batch, f"prefix {i}"
     assert tracker.n_seen == 200
 

@@ -1,13 +1,7 @@
-"""End-to-end behaviour tests: the fused pipeline reproduces the paper's
-headline claim (cross-loop parallelism with exact semantics), training
-learns, serving generates, and the multi-device dry-run lowers."""
-
-import os
-import subprocess
-import sys
+"""End-to-end behaviour test: the fused pipeline reproduces the paper's
+headline claim (cross-loop parallelism with exact semantics)."""
 
 import numpy as np
-import pytest
 
 
 def test_paper_headline_fused_speedup_and_exactness():
@@ -24,141 +18,3 @@ def test_paper_headline_fused_speedup_and_exactness():
     for k in oracle:
         np.testing.assert_allclose(fus.arrays[k], oracle[k], atol=1e-12)
 
-
-@pytest.mark.slow
-def test_training_learns_tiny_model(tmp_path):
-    from repro.launch import train
-
-    losses = train.main([
-        "--arch", "qwen3-14b", "--reduced", "--steps", "30",
-        "--batch", "4", "--seq", "64", "--lr", "3e-3",
-        "--ckpt-dir", str(tmp_path),
-    ])
-    assert losses[-1] < losses[0] - 0.3  # actually learning
-
-
-@pytest.mark.slow
-def test_training_resume_exact(tmp_path):
-    """Fault-tolerance invariant: 20 straight steps == 10 steps + crash +
-    resume + 10 steps (bitwise data stream, same optimizer state)."""
-    from repro.launch import train
-
-    a = train.main([
-        "--arch", "starcoder2-7b", "--reduced", "--steps", "20",
-        "--batch", "2", "--seq", "32", "--ckpt-dir", str(tmp_path / "a"),
-        "--ckpt-every", "5",
-    ])
-    train.main([
-        "--arch", "starcoder2-7b", "--reduced", "--steps", "10",
-        "--total-steps", "20",  # same LR horizon as the straight run
-        "--batch", "2", "--seq", "32", "--ckpt-dir", str(tmp_path / "b"),
-        "--ckpt-every", "5",
-    ])
-    b = train.main([
-        "--arch", "starcoder2-7b", "--reduced", "--steps", "20",
-        "--batch", "2", "--seq", "32", "--ckpt-dir", str(tmp_path / "b"),
-        "--ckpt-every", "5", "--resume",
-    ])
-    np.testing.assert_allclose(a[-1], b[-1], rtol=1e-4)
-
-
-def test_serving_generates():
-    from repro.launch import serve
-
-    toks = serve.main([
-        "--arch", "gemma3-4b", "--reduced", "--batch", "2",
-        "--prompt-len", "8", "--max-new", "8",
-    ])
-    assert toks.shape == (2, 8)
-    assert np.asarray(toks).max() > 0
-
-
-@pytest.mark.slow
-def test_multi_device_dryrun_subprocess():
-    """Proves the sharding config is coherent on a multi-device mesh
-    without polluting this process's device count: a subprocess forces 8
-    CPU devices and lowers a reduced config on a 2x4 mesh."""
-    code = """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import jax, jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.configs import base as configs
-from repro.distributed import partition
-from repro.launch import steps as steps_lib
-from repro.launch.mesh import make_host_mesh
-from repro.models import layers as L
-from repro.models import transformer as T
-from repro.optim import adamw
-
-mesh = make_host_mesh(2, 4)
-cfg = configs.get("qwen3-14b").reduced()
-dt = L.FP32
-params = T.init_params(jax.random.PRNGKey(0), cfg, dt)
-specs = partition.validate_divisibility(partition.param_specs(params), params, mesh)
-p_sh = partition.shardings_of(specs, mesh)
-params = jax.tree.map(lambda a, s: jax.device_put(a, s), params, p_sh)
-opt = adamw.init_state(params)
-batch = {
-    "tokens": jnp.zeros((8, 64), jnp.int32),
-    "targets": jnp.zeros((8, 64), jnp.int32),
-}
-b_sh = {k: NamedSharding(mesh, P(("data",), None)) for k in batch}
-batch = jax.tree.map(lambda a, s: jax.device_put(a, s), batch, b_sh)
-step = jax.jit(steps_lib.make_train_step(cfg, adamw.AdamWConfig(), dt))
-params2, opt2, metrics = step(params, opt, batch)
-assert jnp.isfinite(metrics["loss"])
-# run a second step to prove state threading
-params3, opt3, m2 = step(params2, opt2, batch)
-print("MULTIDEV_OK", float(metrics["loss"]), float(m2["loss"]))
-"""
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src"
-    out = subprocess.run(
-        [sys.executable, "-c", code], capture_output=True, text=True,
-        env=env, cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        timeout=600,
-    )
-    assert "MULTIDEV_OK" in out.stdout, out.stderr[-2000:]
-
-
-@pytest.mark.slow
-def test_elastic_reshard_subprocess():
-    """Checkpoint on an 8-device mesh, restore/reshard on a 4-device
-    mesh: topology-independent checkpoints (elastic scaling)."""
-    code = """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import jax, jax.numpy as jnp
-from repro.checkpoint import manager as ckpt
-from repro.configs import base as configs
-from repro.distributed import partition, elastic
-from repro.models import layers as L
-from repro.models import transformer as T
-
-cfg = configs.get("starcoder2-7b").reduced()
-params = T.init_params(jax.random.PRNGKey(0), cfg, L.FP32)
-mesh8 = elastic.rebuild_mesh(jax.devices(), prefer_model=4)
-sp = partition.validate_divisibility(partition.param_specs(params), params, mesh8)
-params = jax.tree.map(lambda a, s: jax.device_put(a, s), params,
-                      partition.shardings_of(sp, mesh8))
-ckpt.save(params, "/tmp/repro_elastic_test", 1)
-
-# "survivors": only 4 devices
-mesh4 = elastic.rebuild_mesh(jax.devices()[:4], prefer_model=2)
-like = jax.tree.map(jnp.zeros_like, params)
-restored, _ = ckpt.restore(like, "/tmp/repro_elastic_test")
-resharded = elastic.reshard_state(restored, mesh4)
-import numpy as np
-for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(resharded)):
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-print("ELASTIC_OK")
-"""
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src"
-    out = subprocess.run(
-        [sys.executable, "-c", code], capture_output=True, text=True,
-        env=env, cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        timeout=600,
-    )
-    assert "ELASTIC_OK" in out.stdout, out.stderr[-2000:]

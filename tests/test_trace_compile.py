@@ -493,24 +493,3 @@ def test_zero_trip_simulation_still_oracle_exact(n, m):
         )
         for k in oracle:
             np.testing.assert_allclose(res.arrays[k], oracle[k], atol=1e-12)
-
-
-# ---------------------------------------------------------------------------
-# executor plumbing: trace-driven request stream == oracle-hook stream
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("name", ("bnn", "fft", "hist+add", "tanh+spmv"))
-def test_executor_trace_modes_agree(name):
-    from repro.core import executor
-
-    prog, arrays, params = programs.get(name).make(
-        24 if name != "fft" else 32
-    )
-    ra = executor.execute(prog, arrays, params, trace_mode="compiled")
-    rb = executor.execute(prog, arrays, params, trace_mode="interp")
-    assert ra.stats.n_requests == rb.stats.n_requests
-    assert ra.stats.n_waves == rb.stats.n_waves
-    np.testing.assert_array_equal(ra.waves, rb.waves)
-    for k in ra.arrays:
-        np.testing.assert_array_equal(ra.arrays[k], rb.arrays[k])

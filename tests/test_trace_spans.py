@@ -18,7 +18,6 @@ TREE = [
     ("repro.plan", 1),
     ("repro.plan.analyze", 2),
     ("repro.plan.walk", 2),
-    ("repro.plan.trace", 2),
     ("repro.plan.streams", 2),
     ("repro.plan.waves", 2),
     ("repro.plan.analyze", 2),
@@ -158,7 +157,7 @@ def test_each_call_leaves_one_tally():
     n = res.run.n_segments
     assert {k: v[1] for k, v in tally.items()} == {
         "repro.execute": 1, "repro.plan": 1, "repro.plan.analyze": 2,
-        "repro.plan.walk": 1, "repro.plan.trace": 1, "repro.plan.streams": 1,
+        "repro.plan.walk": 1, "repro.plan.streams": 1,
         "repro.plan.waves": 1, "repro.plan.coarsen": 1, "repro.resolve": 1,
         "repro.device": 1, "repro.device.pack": n, "repro.device.launch": n,
         "repro.device.wait": n + 1, "repro.device.check": n,
@@ -167,15 +166,6 @@ def test_each_call_leaves_one_tally():
     assert tally["repro.execute"][0] >= (
         tally["repro.plan"][0] + tally["repro.resolve"][0]
         + tally["repro.device"][0] + tally["repro.unpack"][0])
-
-
-def test_interpreted_stream_opens_no_trace_span():
-    prog, arrays, params = _spmv()
-    executor.execute(prog, arrays, params, backend="numpy",
-                     trace_mode="interp")
-    tally = trace.RECENT[-1]
-    assert "repro.plan.trace" not in tally
-    assert "repro.plan.walk" in tally and "repro.resolve" not in tally
 
 
 def test_span_times_itself_and_nests():
@@ -253,16 +243,21 @@ def test_spec_span_counts_the_speculative_trace():
     assert tally["repro.plan.spec"][0] <= tally["repro.outer"][0]
 
 
-@pytest.mark.parametrize("name, from_walk", [("bfs_front", 1), ("spmv", 0)])
-def test_trace_span_says_where_the_stream_came_from(name, from_walk):
-    """``from_walk`` on ``repro.plan.trace``: 1 where the walk supplied
-    a speculative program's stream, 0 where AGU traces built it."""
+@pytest.mark.parametrize("name", ["spmv", "bfs_front"])
+def test_the_walk_supplies_the_stream(name):
+    """Decoupled or speculative, a plan's request stream is the walk's:
+    ``repro.plan.walk`` counts every request of the plan, and no span
+    builds a second stream."""
     if name == "spmv":
         prog, arrays, params = _spmv()
     else:
         spec = programs.get(name)
         prog, arrays, params = spec.make(spec.default_scale)
-    executor.execute(prog, arrays, params)
-    stats = trace.RECENT[-1]["repro.plan.trace"]
-    assert stats[1] == 1
-    assert stats[2] == {"from_walk": from_walk}
+    res = executor.execute(prog, arrays, params, backend="numpy")
+    tally = trace.RECENT[-1]
+    assert tally["repro.plan.walk"][1] == 1
+    assert tally["repro.plan.walk"][2]["requests"] == res.plan.n_requests > 0
+    assert "repro.plan.trace" not in tally
+    assert "repro.resolve" not in tally
+    with pytest.raises(TypeError, match="trace_mode"):
+        executor.execute(prog, arrays, params, trace_mode="interp")

@@ -86,9 +86,9 @@ PUBLIC_API = [
     ("repro.dse.cache", "ResultCache"),
     ("repro.dse.cache", "SweepJournal"),
     ("repro.dse.spec", "result_projection"),
-    ("repro.launch.analysis", "sweep_speedups"),
-    ("repro.launch.analysis", "pareto_front"),
-    ("repro.launch.analysis", "ParetoTracker"),
+    ("repro.dse.summary", "sweep_speedups"),
+    ("repro.dse.summary", "pareto_front"),
+    ("repro.dse.summary", "ParetoTracker"),
 ]
 
 errors: list[str] = []
