@@ -12,32 +12,38 @@ Execution is two-phase:
               gather/guard/value is pinned request-exact against the
               oracle reference streams, and the per-step
               (addr, write, sval) tables are recorded,
-    device  — the recorded tables are padded to power-of-two lane
-              buckets, stacked into segments of equal width, and each
-              segment runs as **one** jitted ``wave_loop`` call — a
+    device  — the steps are split into segments (``plan_segments``),
+              each segment's recorded tables are packed at once into
+              padded power-of-two tables, and each segment runs as
+              **one** jitted ``wave_loop`` call — a
               ``jax.lax.fori_loop`` over the step tables chaining the
               flat uint32-pair memory image through the carry (XLA
               gather/scatter, the same program on every platform). Final
-              arrays are unpacked from the device image (and the
-              per-step device gathers are checked bit-exact against
-              the resolve phase under ``check=True``).
+              arrays are unpacked from the device image (and under
+              ``check=True`` each segment's device gathers are compared
+              bit for bit, every real lane at once, against the resolve
+              phase's).
 
 The split mirrors what the DU is: the resolve phase *disambiguates*
 (and owns every divergence check); the device phase only *moves* —
 which is why the whole memory schedule compiles to O(segments) kernel
-launches instead of one per step. The device phase's wall time then
-follows the segment count, and it is host work, not kernel time: about
-2.5 ms a segment on a TPU v5e against 0.03 ms of ``wave_loop``, for the
-Python padding of the step tables, the transfers, the dispatch and the
-``check=True`` copy back. Pad lanes target a scratch row past the
-image; pad steps are no-ops (see ``kernel.py``).
+launches instead of one per step. A segment's cost is host work, not
+kernel time: about 1.76 ms on a TPU v5e for the transfers, the
+dispatch, the wait and the copy back, against 0.03 ms of a narrow
+``wave_loop``, plus about 38 ns a padded lane. So ``plan_segments``
+trades launches against padding: a segment's width is that of its
+widest step, and runs of narrow steps are merged into their wider
+neighbours wherever the padding costs less than a launch
+(``_LAUNCH_LANES``). Pad lanes target a scratch row past the image; pad
+steps are no-ops (see ``kernel.py``).
 
 Spans (``repro.trace``): ``repro.resolve`` and ``repro.device`` bound
 the two phases (``resolve_s`` and ``device_s`` are their seconds);
 inside the device phase each segment has ``repro.device.pack`` (the
-padded tables), ``repro.device.launch`` (transfers and the
-``wave_loop`` call; ``new_shape`` is 1 where its shape is new to the
-process, so it compiles), and under ``check`` ``repro.device.wait``
+padded tables; ``lanes`` counts its real requests),
+``repro.device.launch`` (transfers and the ``wave_loop`` call;
+``new_shape`` is 1 where its shape is new to the process, so it
+compiles), and under ``check`` ``repro.device.wait``
 and ``repro.device.check`` (the copy back and compare); the final
 image copy, compare and unpack are ``repro.unpack``.
 
@@ -68,6 +74,10 @@ from repro.core import executor as execlib
 __all__ = ["run_plan", "run_sequential", "WaveExecResult"]
 
 _MIN_BUCKET = 8
+# one segment's fixed host cost (transfers, dispatch, wait), counted in
+# padded lanes: 1.76 ms a segment over 38.5 ns a padded lane on a TPU
+# v5e (derivation in PERF.md)
+_LAUNCH_LANES = 45_000
 # (padded steps, width, image rows) of every segment launched in this
 # process: a key not yet in it makes its launch trace and compile
 _SHAPES_SEEN: set = set()
@@ -87,11 +97,48 @@ class WaveExecResult:
     n_segments: int = 0  # wave_loop launches (fori_loop calls)
 
 
-def _bucket(n: int) -> int:
-    b = _MIN_BUCKET
-    while b < n:
-        b *= 2
-    return b
+def _pow2(n: int) -> int:
+    """Smallest power of two >= ``n`` (1 for ``n`` <= 1)."""
+    return 1 << max(n - 1, 0).bit_length()
+
+
+def _buckets(widths: np.ndarray) -> np.ndarray:
+    """Each step's lane bucket: the smallest power of two >= its width,
+    and at least ``_MIN_BUCKET``."""
+    w = np.asarray(widths, dtype=np.int64)
+    # frexp's exponent of w - 1 is its bit length (exact below 2**53)
+    bits = np.frexp(np.maximum(w - 1, 0).astype(np.float64))[1]
+    return np.maximum(_MIN_BUCKET, np.left_shift(1, bits.astype(np.int64)))
+
+
+def plan_segments(widths) -> list[tuple[int, int, int]]:
+    """Split steps of the given widths (requests a step) into segments,
+    ``[(start step, end step, lane width)]``, one ``wave_loop`` each.
+
+    A segment's width is the bucket of its widest step and its step
+    count is padded to a power of two, so it costs ``_LAUNCH_LANES +
+    steps_pad * width`` under the model, and the split aims at the least
+    total. Runs of steps with equal buckets start as segments; left to
+    right, the open segment takes in the next run wherever the merged
+    segment costs no more than the two apart. Each merge lowers the
+    total or keeps it, so no split costs more than the runs alone.
+    """
+    b = _buckets(widths)
+    if not len(b):
+        return []
+    cut = np.flatnonzero(b[1:] != b[:-1]) + 1
+    starts = [0, *cut.tolist()]
+    ends = [*cut.tolist(), len(b)]
+    segs = []
+    for s, e, wd in zip(starts, ends, b[starts].tolist()):
+        if segs:
+            s0, s1, w0 = segs[-1]
+            apart = _LAUNCH_LANES + _pow2(s1 - s0) * w0 + _pow2(e - s) * wd
+            if _pow2(e - s0) * max(w0, wd) <= apart:
+                segs[-1] = (s0, e, max(w0, wd))
+                continue
+        segs.append((s, e, wd))
+    return segs
 
 
 def _to_u32(f64: np.ndarray) -> np.ndarray:
@@ -147,35 +194,29 @@ def _run(
             max_steps=max_steps,
         )
 
-    # --- device phase: segments of equal-width steps, one wave_loop each -
+    # --- device phase: segments from the cost model, one wave_loop each --
     with trace.span("device", h2d_bytes=mem_f64.nbytes) as device:
         mem_dev = jnp.asarray(_to_u32(mem_f64))
-        widths = [_bucket(len(a)) for a, _, _, _ in rec]
-        segments: list[tuple[int, int]] = []  # (start step, end step)
-        for s, wd in enumerate(widths):
-            if segments and widths[segments[-1][0]] == wd:
-                segments[-1] = (segments[-1][0], s + 1)
-            else:
-                segments.append((s, s + 1))
+        lens = np.fromiter((len(a) for a, _, _, _ in rec), dtype=np.int64,
+                           count=len(rec))
+        segments = plan_segments(lens)
         device.set(segments=len(segments))
-        for s0, s1 in segments:
-            wd = widths[s0]
-            ns = s1 - s0
-            # pad the segment's step count to a power of two as well (pad
-            # steps are no-ops) so compile count is O(log steps · log width)
-            ns_pad = 1
-            while ns_pad < ns:
-                ns_pad *= 2
+        for s0, s1, wd in segments:
+            seg, n = rec[s0:s1], lens[s0:s1]
+            ns, lanes = s1 - s0, int(n.sum())
+            ns_pad = _pow2(ns)
             with trace.span("device.pack", steps=ns, steps_pad=ns_pad,
-                            width=wd):
+                            width=wd, lanes=lanes):
+                # flat (step, lane) slot of every real request: step j's
+                # requests fill row j of the padded tables from lane 0
+                at = np.arange(lanes) + np.repeat(
+                    np.arange(ns) * wd - (np.cumsum(n) - n), n)
                 addrs = np.full((ns_pad, wd), scratch, dtype=np.int32)
                 writes = np.zeros((ns_pad, wd), dtype=bool)
                 svals = np.zeros((ns_pad, wd), dtype=np.float64)
-                for j in range(ns):
-                    a, w, v, _ = rec[s0 + j]
-                    addrs[j, :len(a)] = a
-                    writes[j, :len(a)] = w
-                    svals[j, :len(a)] = v
+                addrs.reshape(-1)[at] = np.concatenate([r[0] for r in seg])
+                writes.reshape(-1)[at] = np.concatenate([r[1] for r in seg])
+                svals.reshape(-1)[at] = np.concatenate([r[2] for r in seg])
             key = (ns_pad, wd, len(mem_f64))
             with trace.span(
                 "device.launch", new_shape=int(key not in _SHAPES_SEEN),
@@ -195,13 +236,15 @@ def _run(
                 with trace.span("device.wait"):
                     vals.block_until_ready()
                 with trace.span("device.check", d2h_bytes=svals.nbytes):
-                    vals_h = np.asarray(vals)
-                    for j in range(ns):
-                        a, _, _, got = rec[s0 + j]
-                        np.testing.assert_array_equal(
-                            _from_u32(vals_h[j])[:len(a)], got,
-                            err_msg="device gather diverged from resolve "
-                            "phase",
+                    # every real lane's gathered bit pattern against the
+                    # resolve phase's
+                    got = np.asarray(vals).reshape(-1, 2)[at]
+                    want = _to_u32(np.concatenate([r[3] for r in seg]))
+                    if not np.array_equal(got, want):
+                        i = int(at[np.flatnonzero((got != want).any(1))[0]])
+                        raise AssertionError(
+                            "device gather diverged from resolve phase at "
+                            f"step {s0 + i // wd}, lane {i % wd}"
                         )
         with trace.span("device.wait"):
             mem_dev.block_until_ready()

@@ -24,16 +24,23 @@ from repro.kernels.wave_exec.kernel import wave_loop
 V5E_HBM_BYTES = 16 * 10**9
 
 # (image rows M incl. the scratch row, padded steps S, lane width W),
-# taken from the plans chip_smoke.py runs
+# taken from the plans chip_smoke.py and the benchmark run
 SHAPES = {
     # tanh+spmv at 2^18 rows: 524,288-word image, its widest segment
     "tanh+spmv@2^18-widest": (524_289, 1, 524_288),
+    # and its largest: four steps padded to 262,144 lanes
+    "tanh+spmv@2^18-largest": (524_289, 4, 262_144),
     # bnn at 8x (66,049 rows): its longest segment, 128 padded steps
     "bnn@8x-longest": (66_049, 128, 512),
     # hist+add at 8x: 586 narrow steps, the longest segment padded to 1024
     "hist+add@8x": (97, 1024, 64),
     # 2^16 lanes over a 2^22-row image
     "w2^16-m2^22": (4_194_305, 16, 65_536),
+    # pagerank_rmat at SCALE 10 (3,072-word image), the widest and the
+    # largest segment plan_segments merges from runs of several widths,
+    # over the benchmark's instances of one seed
+    "pagerank_rmat@10-widest-merged": (3_073, 4, 16_384),
+    "pagerank_rmat@10-largest-merged": (3_073, 1_024, 64),
 }
 
 

@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 from jax.profiler import ProfileData
 
+from bench_programs import bench_program
 from repro import trace
 from repro.core import executor, programs
 from repro.kernels.wave_exec import ops
@@ -32,6 +33,12 @@ TAIL = [("repro.device.wait", 2), ("repro.unpack", 1)]
 
 def _spmv():
     return programs.get("tanh+spmv").make(64)
+
+
+def _pagerank():
+    """A SCALE-6 Graph500 graph: its hub steps and its narrow tail run
+    as two segments."""
+    return bench_program("pagerank_rmat", scale=6)
 
 
 def _program_spans(logdir):
@@ -63,7 +70,7 @@ def _depths(spans):
 def traced(tmp_path_factory):
     """One warm call, then one call of ``execute()`` inside a profiler
     session: (its result, its program spans, its tally)."""
-    prog, arrays, params = _spmv()
+    prog, arrays, params = _pagerank()
     executor.execute(prog, arrays, params, backend="pallas")
     logdir = tmp_path_factory.mktemp("trace")
     with jax.profiler.trace(str(logdir)):
@@ -99,6 +106,9 @@ def test_span_stats_count_what_the_call_did(traced):
     assert stats["repro.plan.walk"][0]["requests"] == res.plan.n_requests
     for st in stats["repro.device.pack"]:
         assert st["steps"] <= st["steps_pad"] < 2 * st["steps"]
+        assert st["steps"] <= st["lanes"] <= st["steps_pad"] * st["width"]
+    assert sum(st["lanes"] for st in stats["repro.device.pack"]) == \
+        res.plan.n_requests
     # the first call warmed every shape; image, tables and copies back
     # are counted from the padded shapes
     assert all(st["new_shape"] == 0 for st in stats["repro.device.launch"])

@@ -6,23 +6,18 @@ benchmark's programs, random programs and hand-built edge cases. A
 program the generator declines keeps the interpreter, in
 ``compile_walk`` and in ``build_wave_plan``."""
 
-import importlib
-import json
 import math
-import pathlib
-import sys
 
 import numpy as np
 import pytest
 
 import loopir_strategies as strat
+from bench_programs import bench_program
 from repro import trace
 from repro.core import executor, loopir as ir, optable, programs
 
 if strat.HAVE_HYPOTHESIS:
     from hypothesis import given
-
-ROOT = pathlib.Path(__file__).resolve().parents[1]
 
 
 def _names(program):
@@ -146,28 +141,13 @@ def test_registered_kernels(name):
     assert check_walks_agree(prog, arrays, params)
 
 
-def _bench_program(config, **override):
-    sys.path.insert(0, str(ROOT))
-    try:
-        mod = importlib.import_module(f"bench.configs.{config}")
-        ref = importlib.import_module(f"bench.configs.{config}_ref")
-    finally:
-        sys.path.remove(str(ROOT))
-    params = json.loads((ROOT / f"bench/configs/{config}.json").read_text())
-    params = dict(params["params"], **override)
-    arrays, pp = ref.generate(
-        params, np.random.default_rng(11), np.random.default_rng(12)
-    )
-    return mod.build(params), arrays, pp
-
-
 @pytest.mark.parametrize("config,override", [
     ("tanh_spmv", {"nx": 4, "ny": 4, "nz": 4, "level": 0}),
     ("tanh_spmv", {"nx": 8, "ny": 8, "nz": 8, "level": 1}),
     ("pagerank_rmat", {"scale": 5}),
 ], ids=["spmv_4cubed", "spmv_level1", "pagerank_scale5"])
 def test_bench_programs(config, override):
-    assert check_walks_agree(*_bench_program(config, **override))
+    assert check_walks_agree(*bench_program(config, **override))
 
 
 # ---------------------------------------------------------------------------
