@@ -129,7 +129,17 @@ class WavePlan:
          loads sit in strictly earlier *steps* — so one step may gather
          all its loads against the pre-step image and then scatter all
          its valid stores. ``batch_waves=False`` degenerates steps to
-         waves (``req_step == req_wave``).
+         waves (``req_step == req_wave``),
+      6. dataflow through intra-PE locals: where a store's value or
+         guard reads a local fed by loads (``optable.fed_locals``; not
+         a cross-PE streamed local, which goes through its FIFO pop),
+         the store depends on exactly the load requests the local's
+         current value was folded from — those of the SetLocal
+         executions since the local was last set from a load-free
+         expression (``local_maps`` → ``local_refs`` → ``local_deps``)
+         — and sits in a strictly later wave and step than each. A
+         backend computes that value by folding the SetLocal bodies
+         (``local_tables``) in program order over its own gathers.
     """
 
     program: ir.Program
@@ -171,6 +181,26 @@ class WavePlan:
     # programs run the unhooked walk); ``drive_plan(validate_hints=
     # True)`` then refuses rather than silently skipping.
     hint_checks: Optional[list] = dataclasses.field(default_factory=list)
+    # dataflow through intra-PE locals (contract 6): the SetLocal places
+    # whose fold gives stores their CLocal values (core/optable), and
+    # one *event* per execution of such a place, in program order —
+    # its place number, the ordinal of the request of each of the
+    # place's ``deps`` it reads and the event holding the current value
+    # of each of its ``locals`` (rows padded with -1), its captured
+    # slots (per place, by execution) — plus, per store op and local,
+    # the event each store request reads
+    local_tables: dict[int, optablelib.LocalTable] = dataclasses.field(
+        default_factory=dict)
+    local_stmt: np.ndarray = dataclasses.field(
+        default_factory=lambda: np.zeros(0, dtype=np.int32))
+    local_deps: np.ndarray = dataclasses.field(
+        default_factory=lambda: np.zeros((0, 0), dtype=np.int64))
+    local_refs: np.ndarray = dataclasses.field(
+        default_factory=lambda: np.zeros((0, 0), dtype=np.int64))
+    local_env: dict[int, list[np.ndarray]] = dataclasses.field(
+        default_factory=dict)
+    local_maps: dict[str, dict[str, np.ndarray]] = dataclasses.field(
+        default_factory=dict)
 
     @property
     def n_requests(self) -> int:
@@ -286,14 +316,14 @@ _WALKS: dict[tuple, Optional[Callable]] = {}
 _WALKS_MAX = 256
 
 
-def _compiled_walk(program, aux_exprs, with_loop_hook):
+def _compiled_walk(program, aux_exprs, with_loop_hook, local_exprs=None):
     """(the program's generated walk or None, whether this call made
     it)."""
     key = (program.fingerprint(), tuple(sorted(aux_exprs.items())),
-           with_loop_hook)
+           with_loop_hook, tuple(sorted((local_exprs or {}).items())))
     if key in _WALKS:
         return _WALKS[key], False
-    walker = ir.compile_walk(program, aux_exprs, with_loop_hook)
+    walker = ir.compile_walk(program, aux_exprs, with_loop_hook, local_exprs)
     if len(_WALKS) >= _WALKS_MAX:
         del _WALKS[next(iter(_WALKS))]
     _WALKS[key] = walker
@@ -353,9 +383,12 @@ def _plan(
                         name: f"~pop:{eidx}" for eidx, name in ins
                     }
         tables = optablelib.compile_store_tables(program, stream_deps or None)
+        local_tables = optablelib.compile_local_tables(program, tables)
     aux_exprs = {
         op_id: t.env_exprs for op_id, t in tables.items() if t.env_exprs
     }
+    # every place of a fed local reports its executions (contract 6)
+    local_exprs = {k: t.env_exprs for k, t in local_tables.items()} or None
 
     # --- pass 1: hooked oracle walk (reference + CU operand capture) -----
     per_op_vv: dict[str, list[tuple[bool, Optional[float]]]] = {}
@@ -397,6 +430,17 @@ def _plan(
 
     def aux_hook(op_id, values):
         env_rows[op_id].append(values)
+
+    # one event per execution of a fed local's SetLocal place: its place
+    # number and position in the real request stream
+    ev_stmt: list[int] = []
+    ev_pos: list[int] = []
+    local_rows: dict[int, list[tuple]] = {k: [] for k in local_tables}
+
+    def local_hook(k, values):
+        ev_stmt.append(k)
+        ev_pos.append(n_real[0])
+        local_rows[k].append(values)
 
     def hook(op_id, addr, is_store, valid, value):
         n_real[0] += 1
@@ -458,20 +502,23 @@ def _plan(
             speculate.interpret_hooked(
                 program, arrays, params, hook,
                 aux_exprs=aux_exprs, aux_hook=aux_hook,
+                local_exprs=local_exprs, local_hook=local_hook,
             )
         else:
             walker, compiles = _compiled_walk(
-                program, aux_exprs, loop_hook is not None
+                program, aux_exprs, loop_hook is not None, local_exprs,
             )
             if walker is not None:
                 compiled = walker(
                     program, arrays, params, hook, aux_hook, loop_hook,
+                    local_hook,
                 ) is not None
             if not compiled:
                 ir.interpret(
                     program, arrays, params, trace_hook=hook,
                     aux_exprs=aux_exprs, aux_hook=aux_hook,
-                    loop_hook=loop_hook,
+                    loop_hook=loop_hook, local_exprs=local_exprs,
+                    local_hook=local_hook,
                 )
         walk.set(requests=n_real[0], compiled=int(compiled),
                  walk_compiles=int(compiles))
@@ -557,6 +604,22 @@ def _plan(
             if value is not None:
                 req_value[i] = value
 
+    # --- dataflow through intra-PE locals (contract 6) -------------------
+    local: dict = {}
+    if local_tables:
+        with trace.span("plan.locals") as loc:
+            n_mem_ops = len(program.mem_ops())
+            local, edges = _local_events(
+                local_tables, tables, ev_stmt, ev_pos, local_rows,
+                req_op[req_op < n_mem_ops] if fifo_events else req_op,
+                op_index,
+            )
+            loc.set(edges=edges)
+    store_local_rows = {
+        o: [m.tolist() for m in per.values()]
+        for o, per in local.get("local_maps", {}).items()
+    }
+
     # --- pass 2: wave assignment (one program-order sweep) ---------------
     with trace.span("plan.waves") as sweep:
         waves = np.zeros(n, dtype=np.int64)
@@ -577,6 +640,34 @@ def _plan(
         pe_frontier: dict[int, int] = {}
         push_pe: dict[str, int] = {}
         pop_pe: dict[str, int] = {}
+
+        # per event: max wave over the loads its value was folded from,
+        # filled in program order as stores ask (each event's loads
+        # precede it, so the sweep has placed them)
+        ready: list[int] = []
+        if store_local_rows:
+            ev_stmt_l = local["local_stmt"].tolist()
+            ref_cols = [c.tolist() for c in local["local_refs"].T]
+            dep_cols = [c.tolist() for c in local["local_deps"].T]
+            # per place: (waves of the load op, ordinals column) per dep
+            ev_deps = {
+                k: [(wave_of_load.setdefault(ld, []), dep_cols[j])
+                    for j, ld in enumerate(t.deps)]
+                for k, t in local_tables.items()
+            }
+
+        def advance(e):
+            for x in range(len(ready), e + 1):
+                m = -1
+                for lw, col in ev_deps[ev_stmt_l[x]]:
+                    o = col[x]
+                    if o >= 0 and lw[o] > m:
+                        m = lw[o]
+                for col in ref_cols:
+                    r = col[x]
+                    if r >= 0 and ready[r] > m:
+                        m = ready[r]
+                ready.append(m)
         if fifo_spec:
             for e in fifo_spec.edges:
                 push_pe[f"~push:{e.idx}"] = e.prod_pe
@@ -598,6 +689,13 @@ def _plan(
                         lw = wave_of_load[ld][m]
                         if lw > fm:
                             fm = lw
+                for evs in store_local_rows.get(o, ()):
+                    e = evs[k]
+                    if e >= 0:
+                        if e >= len(ready):
+                            advance(e)
+                        if ready[e] > fm:
+                            fm = ready[e]
                 ppe = push_pe.get(o)
                 if ppe is not None:
                     fm = max(fm, pe_frontier.get(ppe, -1))
@@ -744,7 +842,71 @@ def _plan(
         tables=tables, env=env, dep_maps=dep_maps,
         array_order=protected, base=base, mem_size=off,
         stats=stats, fifo_edges=fifo_meta, hint_checks=hint_checks,
+        local_tables=local_tables, **local,
     )
+
+
+def _local_events(local_tables, tables, ev_stmt, ev_pos, local_rows,
+                  real_op, op_index) -> tuple[dict, int]:
+    """The plan's contract-6 fields (``local_stmt``, ``local_deps``,
+    ``local_refs``, ``local_env``, ``local_maps``) from the walk's
+    events, and the number of load→store edges through locals.
+
+    ``ev_stmt``/``ev_pos`` give each event's place and the number of
+    real requests before it; ``real_op`` is the real request stream's
+    op indices. An event reads, of each load op, its latest request
+    before the event, and of each local, the latest earlier event that
+    sets it; a store request reads the latest event of the local before
+    it. Edges are counted along each local's chain of events.
+    """
+    n_ev = len(ev_stmt)
+    stmt = np.asarray(ev_stmt, dtype=np.int32)
+    pos = np.asarray(ev_pos, dtype=np.int64)
+    width = max(len(t.deps) for t in local_tables.values())
+    deps = np.full((n_ev, width), -1, dtype=np.int64)
+    width = max(len(t.locals) for t in local_tables.values())
+    refs = np.full((n_ev, width), -1, dtype=np.int64)
+    env: dict[int, list[np.ndarray]] = {}
+    positions: dict[str, np.ndarray] = {}
+
+    def at(o):  # real positions of op o's requests
+        if o not in positions:
+            positions[o] = np.flatnonzero(real_op == op_index[o])
+        return positions[o]
+
+    setters: dict[str, np.ndarray] = {}
+
+    def set_by(name):  # events that set ``name``, in order
+        if name not in setters:
+            places = [k for k, t in local_tables.items() if t.name == name]
+            setters[name] = np.flatnonzero(np.isin(stmt, places))
+        return setters[name]
+
+    n_dep = np.zeros(n_ev, dtype=np.int64)
+    for k, t in local_tables.items():
+        evs = np.flatnonzero(stmt == k)
+        for j, ld in enumerate(t.deps):
+            o = np.searchsorted(at(ld), pos[evs], side="left") - 1
+            deps[evs, j] = o
+            n_dep[evs] += o >= 0
+        for j, name in enumerate(t.locals):
+            s = set_by(name)
+            r = np.searchsorted(s, evs, side="left") - 1
+            refs[evs, j] = np.where(r >= 0, s[np.maximum(r, 0)], -1)
+        env[k] = [np.asarray([row[j] for row in local_rows[k]])
+                  for j in range(len(t.env_exprs))]
+    chain = _along_chains(n_dep, refs, np.add)  # loads in each chain
+    maps: dict[str, dict[str, np.ndarray]] = {}
+    edges = 0
+    for o, t in tables.items():
+        for name in t.locals:
+            s = set_by(name)
+            r = np.searchsorted(pos[s], at(o), side="right") - 1
+            ev = np.where(r >= 0, s[np.maximum(r, 0)], -1)
+            maps.setdefault(o, {})[name] = ev
+            edges += int(chain[ev[ev >= 0]].sum())
+    return {"local_stmt": stmt, "local_deps": deps, "local_refs": refs,
+            "local_env": env, "local_maps": maps}, edges
 
 
 def wave_store_inputs(
@@ -822,6 +984,25 @@ def validate_plan(plan: WavePlan) -> None:
             ), f"store {op_id} shares a step with its {ld} inputs"
             # -1 rows must be guard-invalid (contract 3)
             assert np.all(plan.req_valid[rows][~ok] == False)  # noqa: E712
+    # 6. a store reading a fed local is in a strictly later wave and
+    # step than every load its value was folded from
+    if plan.local_maps:
+        chain_wave = _chain_max(plan, lv_wave)
+        chain_step = _chain_max(plan, lv_step)
+        for op_id, per in plan.local_maps.items():
+            rows = np.nonzero(plan.req_op == plan.op_ids.index(op_id))[0]
+            k = plan.req_ordinal[rows]
+            for name, ev in per.items():
+                e = ev[k]
+                ok = e >= 0
+                assert np.all(waves[rows][ok] > chain_wave[e[ok]]), (
+                    f"store {op_id} not strictly after the loads its "
+                    f"local {name!r} was folded from"
+                )
+                assert np.all(steps[rows][ok] > chain_step[e[ok]]), (
+                    f"store {op_id} shares a step with the loads its "
+                    f"local {name!r} was folded from"
+                )
     # 5. steps coarsen waves order-preservingly: the step index is a
     # non-decreasing function of the wave index
     if n:
@@ -872,6 +1053,121 @@ def validate_plan(plan: WavePlan) -> None:
         assert np.all(cs > ps), (
             f"fifo edge {fe['idx']}: pop shares a step with its push"
         )
+
+
+def _along_chains(own: np.ndarray, refs: np.ndarray, ufunc) -> np.ndarray:
+    """Per local event, ``own`` combined by ``ufunc`` (``np.maximum`` or
+    ``np.add``) over the event and every event it reads, transitively:
+    over the chain its value was folded along. Where each event reads
+    at most one (a register accumulator), pointer jumping does it in
+    log2(chain length) vectorised rounds."""
+    out = own.copy()
+    if refs.shape[1] == 1:
+        r = refs[:, 0].copy()
+        while True:
+            m = np.flatnonzero(r >= 0)
+            if not len(m):
+                return out
+            out[m] = ufunc(out[m], out[r[m]])
+            r[m] = r[r[m]]
+    out_l = out.tolist()
+    for x, row in enumerate(refs.tolist()):
+        for r in row:
+            if r >= 0:
+                out_l[x] = ufunc(out_l[x], out_l[r]).item()
+    return np.asarray(out_l, dtype=own.dtype)
+
+
+def _per_load(plan: WavePlan, of_load: dict[str, np.ndarray]) -> np.ndarray:
+    """Per local event, the max of ``of_load[ld][ordinal]`` over the load
+    requests the event itself reads (-1 for none)."""
+    own = np.full(len(plan.local_stmt), -1, dtype=np.int64)
+    for k, t in plan.local_tables.items():
+        evs = np.flatnonzero(plan.local_stmt == k)
+        for j, ld in enumerate(t.deps):
+            o = plan.local_deps[evs, j]
+            got = np.asarray(of_load[ld])[np.maximum(o, 0)]
+            own[evs] = np.maximum(own[evs], np.where(o >= 0, got, -1))
+    return own
+
+
+def _chain_max(plan: WavePlan, of_load: dict[str, np.ndarray]) -> np.ndarray:
+    """Per local event, the max of ``of_load[ld][ordinal]`` over the load
+    requests its value was folded from (-1 for none)."""
+    return _along_chains(_per_load(plan, of_load), plan.local_refs,
+                         np.maximum)
+
+
+class _Fold:
+    """The values of a plan's local events (contract 6): each event's
+    SetLocal body over the load values resolved so far, its captured
+    slots and the values of the events it reads, evaluated as the
+    oracle evaluates it (``LocalTable.scalar``). An event is folded
+    once the step of the last load its value was folded from has run
+    (``upto``), events of one step in program order, so those it reads
+    come first."""
+
+    def __init__(self, plan: WavePlan, frozen, step_of):
+        stmt = plan.local_stmt
+        n = len(stmt)
+        self.val: list = [None] * n
+        # the resolved values of the loads the events read, as Python
+        # floats (``loaded``)
+        self.lists = {
+            ld: [0.0] * plan.op_nreq[ld]
+            for t in plan.local_tables.values() for ld in t.deps
+        }
+        ordinal = np.zeros(n, dtype=np.int64)
+        for k in plan.local_tables:
+            evs = np.flatnonzero(stmt == k)
+            ordinal[evs] = np.arange(len(evs))
+        deps = [c.tolist() for c in plan.local_deps.T]
+        refs = [c.tolist() for c in plan.local_refs.T]
+        ordinal = ordinal.tolist()
+        self.fns = {
+            k: t.scalar(
+                tuple(self.lists[ld] for ld in t.deps),
+                [a.tolist() for a in plan.local_env.get(k, ())],
+                self.val, tuple(frozen[a] for a in t.frozen_reads),
+                deps, refs, ordinal,
+            )
+            for k, t in plan.local_tables.items()
+        }
+        # the last step whose loads an event's value was folded from
+        load_step = {
+            ld: step_of[np.flatnonzero(
+                plan.req_op == plan.op_ids.index(ld))]  # ordinal order
+            for ld in self.lists
+        }
+        ready = _chain_max(plan, load_step)
+        self.order = np.argsort(ready, kind="stable").tolist()
+        self.ready = ready[self.order]
+        self.stmt = stmt.tolist()
+        self.done = 0
+
+    def loaded(self, op_id: str, ordinals: np.ndarray, values: np.ndarray):
+        """Record resolved load values that events may read."""
+        lst = self.lists.get(op_id)
+        if lst is not None:
+            for o, v in zip(ordinals.tolist(), values.tolist()):
+                lst[o] = v
+
+    def upto(self, step: int) -> None:
+        """Fold every event whose loads ran in steps before ``step``."""
+        end = int(np.searchsorted(self.ready, step, side="left"))
+        if end <= self.done:
+            return
+        val, fns, stmt = self.val, self.fns, self.stmt
+        with np.errstate(all="ignore"):
+            for x in self.order[self.done:end]:
+                val[x] = fns[stmt[x]](x)
+        self.done = end
+
+    def values(self, events: np.ndarray) -> np.ndarray:
+        """Values of the given events (NaN for -1)."""
+        val = self.val
+        return np.asarray([val[e] if e >= 0 else np.nan
+                           for e in events.tolist()], dtype=np.float64)
 
 
 def validate_plan_hints(plan: WavePlan) -> None:
@@ -934,6 +1230,7 @@ def drive_plan(
         op_id: np.zeros(plan.op_nreq[op_id], dtype=np.float64)
         for op_id, s in plan.op_is_store.items() if not s
     }
+    fold = _Fold(plan, frozen, step_of) if plan.local_maps else None
     order = np.argsort(step_of, kind="stable")
     bounds = np.searchsorted(step_of[order], np.arange(n_steps + 1))
     steps = 0
@@ -952,8 +1249,15 @@ def drive_plan(
             rows = batch[sel]
             op_id = plan.op_ids[op_i]
             deps, env, nn = wave_store_inputs(plan, op_id, rows, lv_streams)
-            v = plan.tables[op_id].eval_value(deps, env, frozen, nn, lib=lib)
-            g = plan.tables[op_id].eval_guard(deps, env, frozen, nn, lib=lib)
+            locs = None
+            if op_id in plan.local_maps:
+                fold.upto(w)
+                k = plan.req_ordinal[rows]
+                locs = {name: fold.values(ev[k])
+                        for name, ev in plan.local_maps[op_id].items()}
+            table = plan.tables[op_id]
+            v = table.eval_value(deps, env, frozen, nn, lib=lib, locs=locs)
+            g = table.eval_guard(deps, env, frozen, nn, lib=lib, locs=locs)
             v, g = np.asarray(v, dtype=np.float64), np.asarray(g)
             if check:
                 np.testing.assert_array_equal(
@@ -982,9 +1286,10 @@ def drive_plan(
                 )
             for op_i in np.unique(plan.req_op[loads]):
                 m = plan.req_op[loads] == op_i
-                lv_streams[plan.op_ids[op_i]][
-                    plan.req_ordinal[loads[m]]
-                ] = got_loads[m]
+                k = plan.req_ordinal[loads[m]]
+                lv_streams[plan.op_ids[op_i]][k] = got_loads[m]
+                if fold is not None:
+                    fold.loaded(plan.op_ids[op_i], k, got_loads[m])
     return steps, True
 
 

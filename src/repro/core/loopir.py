@@ -53,6 +53,12 @@ class Expr:
     def __rmul__(self, o):
         return Bin("*", wrap(o), self)
 
+    def __truediv__(self, o):
+        return Bin("/", self, wrap(o))
+
+    def __rtruediv__(self, o):
+        return Bin("/", wrap(o), self)
+
     def __floordiv__(self, o):
         return Bin("//", self, wrap(o))
 
@@ -160,13 +166,14 @@ NP_UN_FNS: dict[str, Callable] = _UN_FNS
 
 # vectorized counterparts of _binop, used by the affine trace compiler
 # (core/affine.py); numpy's //, % match Python's semantics on ints and
-# floats, min/max become elementwise minimum/maximum. Keep the two
-# tables in sync: every op here must behave elementwise exactly like
-# _binop does on scalars.
+# floats, / is IEEE float64 true division as _fdiv's, min/max become
+# elementwise minimum/maximum. Keep the two tables in sync: every op
+# here must behave elementwise exactly like _binop does on scalars.
 NP_BINOPS: dict[str, Callable] = {
     "+": np.add,
     "-": np.subtract,
     "*": np.multiply,
+    "/": np.true_divide,
     "//": np.floor_divide,
     "%": np.mod,
     "min": np.minimum,
@@ -180,6 +187,15 @@ NP_BINOPS: dict[str, Callable] = {
 }
 
 
+def _fdiv(a, b):
+    """IEEE float64 true division: a zero divisor gives +-inf or nan, as
+    numpy's does, where Python's ``/`` raises."""
+    if b == 0:
+        with np.errstate(divide="ignore", invalid="ignore"):
+            return float(np.float64(a) / np.float64(b))
+    return a / b
+
+
 def _binop(op: str, a, b):
     if op == "+":
         return a + b
@@ -187,6 +203,8 @@ def _binop(op: str, a, b):
         return a - b
     if op == "*":
         return a * b
+    if op == "/":
+        return _fdiv(a, b)
     if op == "//":
         return a // b
     if op == "%":
@@ -395,6 +413,35 @@ class Program:
         return loop_pos, op_pos
 
 
+def set_locals(program: Program) -> tuple[SetLocal, ...]:
+    """Every SetLocal of the program in program order, one entry per
+    place it takes in the forest: the position of an entry is the number
+    ``local_exprs`` and ``local_hook`` give that statement."""
+    out: list = []
+
+    def walk(stmts):
+        for s in stmts:
+            if isinstance(s, Loop):
+                walk(s.body)
+            elif isinstance(s, SetLocal):
+                out.append(s)
+
+    walk(program.loops)
+    return tuple(out)
+
+
+def _n_set_locals(s, memo: dict) -> int:
+    """SetLocal places inside statement ``s`` (1 for a SetLocal)."""
+    if isinstance(s, SetLocal):
+        return 1
+    if not isinstance(s, Loop):
+        return 0
+    n = memo.get(id(s))
+    if n is None:
+        n = memo[id(s)] = sum(_n_set_locals(t, memo) for t in s.body)
+    return n
+
+
 # ---------------------------------------------------------------------------
 # Sequential oracle interpreter
 # ---------------------------------------------------------------------------
@@ -470,6 +517,8 @@ def interpret(
     aux_exprs: Optional[dict[str, tuple]] = None,
     aux_hook: Optional[Callable] = None,
     loop_hook: Optional[Callable] = None,
+    local_exprs: Optional[dict[int, tuple]] = None,
+    local_hook: Optional[Callable] = None,
 ) -> dict[str, np.ndarray]:
     """Run the program sequentially; returns the final array state.
 
@@ -500,6 +549,13 @@ def interpret(
     one-token-per-leaf-instance push/pop stream without re-deriving
     loop structure.
 
+    ``local_exprs`` maps a SetLocal's number (its position in
+    ``set_locals(program)``) to a tuple of extra expressions; each time
+    that statement runs, they are evaluated in its environment before
+    the assignment and the results passed to ``local_hook(number,
+    values_tuple)``. This is how ``core/optable`` captures the operands
+    of a register accumulator's fold (``compile_local_tables``).
+
     Load values are visible downstream of their ``Load`` within the
     enclosing body *and* inside nested loops of that body — including
     loop trip counts and ivar updates. Load-dependent trips (the §6
@@ -528,9 +584,12 @@ def interpret(
                         vals.append(np.nan)
             aux_hook(op_id, tuple(vals))
 
-    def run_body(stmts: Sequence[Stmt], env: _Env, outer_loadvals):
+    n_sets: dict[int, int] = {}
+
+    def run_body(stmts: Sequence[Stmt], env: _Env, outer_loadvals, at):
         # chained visibility: loads of enclosing iterations stay readable
         loadvals: dict[str, float] = dict(outer_loadvals)
+        # ``at``: the number of the next SetLocal place in program order
         for s in stmts:
             if isinstance(s, Load):
                 a = int(_eval(s.addr, env, arrays, params, loadvals))
@@ -554,15 +613,22 @@ def interpret(
                     trace_hook(s.id, a, True, True, float(v))
                 arrays[s.array][a] = v
             elif isinstance(s, SetLocal):
+                if local_exprs is not None and at in local_exprs:
+                    local_hook(at, tuple(
+                        _eval(e, env, arrays, params, loadvals)
+                        for e in local_exprs[at]
+                    ))
+                at += 1
                 v = _eval(s.value, env, arrays, params, loadvals)
                 if not env.set_existing(s.name, v):
                     env.define(s.name, v)
             elif isinstance(s, Loop):
-                run_loop(s, env, loadvals)
+                run_loop(s, env, loadvals, at)
+                at += _n_set_locals(s, n_sets)
             else:
                 raise TypeError(f"unknown stmt {s!r}")
 
-    def run_loop(loop: Loop, env: _Env, loadvals):
+    def run_loop(loop: Loop, env: _Env, loadvals, at):
         if loop_hook is not None:
             loop_hook(loop, "enter", env.get)
         outer = _Env(env)
@@ -572,7 +638,7 @@ def interpret(
         for i in range(trip):
             inner = _Env(outer)
             inner.define(loop.var, i)
-            run_body(loop.body, inner, loadvals)
+            run_body(loop.body, inner, loadvals, at)
             for iv in loop.ivars:
                 cur = outer.get(iv.name)
                 step = _eval(iv.step, inner, arrays, params, loadvals)
@@ -582,8 +648,10 @@ def interpret(
         return
 
     top = _Env()
+    at = 0
     for lp in program.loops:
-        run_loop(lp, top, {})
+        run_loop(lp, top, {}, at)
+        at += _n_set_locals(lp, n_sets)
     return arrays
 
 
@@ -592,7 +660,7 @@ def interpret(
 # ---------------------------------------------------------------------------
 
 _CMP_OPS = frozenset(("<", "<=", ">", ">=", "==", "!="))
-_ARITH_OPS = frozenset(("+", "-", "*", "//", "%"))
+_ARITH_OPS = frozenset(("+", "-", "*", "/", "//", "%"))
 
 
 class _Uncompilable(Exception):
@@ -628,8 +696,11 @@ class _WalkSource:
     that learns more asks for another (``grown``).
     """
 
-    def __init__(self, program, aux_exprs, with_loop_hook, disagree, boolish):
+    def __init__(self, program, aux_exprs, with_loop_hook, disagree, boolish,
+                 local_exprs):
         self.aux = aux_exprs
+        self.local = local_exprs
+        self.n_set = 0  # SetLocal places met so far, in program order
         self.hooked = with_loop_hook
         self.disagree = disagree
         self.boolish = boolish
@@ -719,9 +790,14 @@ class _WalkSource:
             if e.op not in _ARITH_OPS:
                 raise _Uncompilable(f"unknown binop {e.op}")
             self.arith(aa, ba, ab, bb)
-            if e.op in ("//", "%") and not (aa and ab) and not (
-                isinstance(e.b, Const) and type(e.b.v) in (int, float) and e.b.v
-            ):
+            nonzero = isinstance(e.b, Const) and type(e.b.v) in (int, float) \
+                and e.b.v
+            if e.op == "/":
+                # Python's / raises on a zero divisor: fdiv_ gives IEEE's
+                if nonzero:
+                    return f"({a} / {b})", aa and ab, False, False
+                return f"fdiv_({a}, {b})", aa and ab, False, False
+            if e.op in ("//", "%") and not (aa and ab) and not nonzero:
                 # a zero divisor raises on Python numbers and gives inf
                 # or nan on numpy scalars
                 raise _Uncompilable(f"'{e.op}' by a value that may be 0")
@@ -838,6 +914,13 @@ class _WalkSource:
                     self.run_aux(s.id, scope, loads, d + 1, strict=False)
                     self.emit(d + 1, f"trace_hook({s.id!r}, a_, True, False, None)")
             elif isinstance(s, SetLocal):
+                exprs = self.local.get(self.n_set)
+                self.n_set += 1
+                if exprs is not None:
+                    srcs = "".join(
+                        self.expr(e, scope, loads)[0] + ", " for e in exprs
+                    )
+                    self.emit(d, f"local_hook({self.n_set - 1}, ({srcs}))")
                 src, agree, boolish, _ = self.expr(s.value, scope, loads)
                 try:
                     b = self.resolve(scope, s.name)
@@ -852,9 +935,10 @@ class _WalkSource:
 
     def source(self) -> str:
         head = [
-            "def walk(A, K, P, C, L, trace_hook, aux_hook, loop_hook):",
+            "def walk(A, K, P, C, L, trace_hook, aux_hook, loop_hook,"
+            " local_hook):",
             "    int_, float_, range_, min_, max_ = int, float, range, min, max",
-            "    nan_ = _nan",
+            "    nan_, fdiv_ = _nan, _fdiv",
         ]
         head += [f"    A{j} = A[{j}]  # {n}" for n, j in self.arrays.items()]
         head += [f"    p{j} = P[{j}]  # {n}" for n, j in self.params.items()]
@@ -879,14 +963,17 @@ def compile_walk(
     program: Program,
     aux_exprs: Optional[dict[str, tuple]] = None,
     with_loop_hook: bool = False,
+    local_exprs: Optional[dict[int, tuple]] = None,
 ) -> Optional[Callable]:
     """``interpret`` with hooks, compiled to one Python function.
 
     Returns ``walk(program, arrays, params, trace_hook=None,
-    aux_hook=None, loop_hook=None)``, which makes the same hook calls in
-    the same order with the same values and returns the same final
-    arrays as ``interpret(program, arrays, params, trace_hook,
-    aux_exprs, aux_hook, loop_hook)``; ``loop_hook`` is called only when
+    aux_hook=None, loop_hook=None, local_hook=None)``, which makes the
+    same hook calls in the same order with the same values and returns
+    the same final arrays as ``interpret(program, arrays, params,
+    trace_hook, aux_exprs, aux_hook, loop_hook, local_exprs,
+    local_hook)``; ``local_hook`` is called where ``local_exprs`` name a
+    statement, and ``loop_hook`` only when
     compiled ``with_loop_hook``, and its ``reader`` answers at the time
     of the call. ``program`` may be any program of the same
     ``fingerprint()``: its Loop objects are the ones ``loop_hook``
@@ -913,12 +1000,14 @@ def compile_walk(
     wraps) and int64 values beyond 2**53 compared with floats.
     """
     aux_exprs = aux_exprs or {}
+    local_exprs = local_exprs or {}
     disagree: set[int] = set()
     boolish: set[int] = set()
     try:
         while True:
             gen = _WalkSource(
-                program, aux_exprs, with_loop_hook, disagree, boolish
+                program, aux_exprs, with_loop_hook, disagree, boolish,
+                local_exprs,
             )
             if not gen.grown:
                 break
@@ -926,7 +1015,7 @@ def compile_walk(
         code = compile(gen.source(), f"<walk of {program.name}>", "exec")
     except (_Uncompilable, SyntaxError):
         return None
-    ns = {"_nan": np.nan, "_UN_FNS": _UN_FNS}
+    ns = {"_nan": np.nan, "_fdiv": _fdiv, "_UN_FNS": _UN_FNS}
     exec(code, ns)
     fn = ns["walk"]
     consts = tuple(gen.consts)
@@ -941,7 +1030,7 @@ def compile_walk(
         ))
 
     def walk(program, arrays, params, trace_hook=None, aux_hook=None,
-             loop_hook=None):
+             loop_hook=None, local_hook=None):
         if loop_hook is not None and not with_loop_hook:
             raise ValueError("walk compiled without with_loop_hook")
         params = params or {}
@@ -960,7 +1049,7 @@ def compile_walk(
         fn(
             data, float if lists else _same, pvals, consts,
             _loops_in_order(program) if with_loop_hook else (),
-            trace_hook or (lambda *_: None), aux_hook, loop_hook,
+            trace_hook or (lambda *_: None), aux_hook, loop_hook, local_hook,
         )
         if lists:
             for j in stored:

@@ -385,6 +385,8 @@ def interpret_hooked(
     trace_hook,
     aux_exprs=None,
     aux_hook=None,
+    local_exprs=None,
+    local_hook=None,
 ) -> dict[str, np.ndarray]:
     """``loopir.interpret`` with the speculative auto-reject applied:
     a load value consumed before it exists even sequentially (e.g. a
@@ -393,11 +395,13 @@ def interpret_hooked(
     program. Other KeyErrors (typo'd array/param names) propagate
     untouched. The single conversion site shared by ``simulate()``
     (via ``oracle_load_streams``) and ``executor.execute``.
-    ``aux_exprs``/``aux_hook`` pass through to ``loopir.interpret``."""
+    ``aux_exprs``/``aux_hook`` and ``local_exprs``/``local_hook`` pass
+    through to ``loopir.interpret``."""
     try:
         return ir.interpret(
             program, arrays, params or {}, trace_hook=trace_hook,
             aux_exprs=aux_exprs, aux_hook=aux_hook,
+            local_exprs=local_exprs, local_hook=local_hook,
         )
     except ir.UnavailableLoadValue as exc:
         raise daelib.LossOfDecoupling(
